@@ -118,6 +118,9 @@ def main(argv=None):
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count={args.hostdev}"
         ).strip()
+    from .compile_cache import enable_compile_cache
+
+    print(f"[benchmarks] compile cache: {enable_compile_cache()}")
 
     failures = []
     for name, fn in _modules():

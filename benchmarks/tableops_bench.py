@@ -2,9 +2,9 @@
 
 Roofline-style per-op report: rows/s and GB/s for every ported hot-path
 primitive — splitmix64 hash, fused partition index, filter compare, the
-two-kernel map expression, fixed-point AGG, and the join probe — across
-``impl`` in {numpy, jax} and row counts, the way planner solve time is
-tracked by ``planner_scale``. The numpy column is the bitwise reference the
+two-kernel map expression, fixed-point AGG and its merge, and the join
+probe — across ``impl`` in {numpy, jax} and row counts, the way planner
+solve time is tracked by ``planner_scale``. The numpy column is the bitwise reference the
 jitted path must beat; ``speedup`` is jax rows/s over numpy rows/s.
 
 ``--smoke`` (CI) swaps throughput for the parity gate: every primitive runs
@@ -32,19 +32,33 @@ N_PARTITIONS = 64
 JOIN_INDEX_KEYS = 1 << 20
 
 
-def _mk_inputs(n: int, seed: int = 7):
+def _mk_inputs(n: int, seed: int = 7, subnormal: bool = False):
+    """Inputs for every op at ``n`` rows; with ``subnormal``, every fifth
+    value of the float columns is a float32 subnormal of either sign."""
     rng = np.random.default_rng(seed)
     keys = rng.integers(0, max(n // 16, 4), n).astype(np.int64)
     a = rng.standard_normal(n).astype(np.float32)
     b = rng.standard_normal(n).astype(np.float32)
+    if subnormal:
+        tiny = np.finfo(np.float32).tiny
+        for col in (a, b):
+            m = len(col[::5])
+            col[::5] = (rng.uniform(1e-6, 1.0, m) * tiny).astype(
+                np.float32) * rng.choice(np.float32([-1, 1]), m)
     w = rng.choice(np.asarray([-2, -1, 1, 2, 3], np.int64), n)
     uniq = np.unique(
         rng.integers(0, 1 << 40, min(JOIN_INDEX_KEYS, max(n // 8, 4)))
     ).astype(np.int64)
     probe = rng.choice(uniq, n) if len(uniq) else keys
     agg_table = {"key": keys, "c0": a, "c1": b, "weight": w}
+    # two signed partial aggregates: merge_agg re-encodes their float64 sums
+    half = n // 2
+    partials = tuple(
+        T.op_agg({k: v[sl] for k, v in agg_table.items()})
+        for sl in (slice(0, half), slice(half, n))
+    )
     return dict(keys=keys, a=a, b=b, w=w, uniq=uniq, probe=probe,
-                agg=agg_table)
+                agg=agg_table, partials=partials)
 
 
 def _ops(inp):
@@ -58,6 +72,7 @@ def _ops(inp):
         "filter": (lambda: dp.filter_mask(inp["a"], 0.0), 5 * n),
         "map": (lambda: dp.map_derived(inp["a"], inp["b"]), 12 * n),
         "agg": (lambda: T.op_agg(inp["agg"]), 28 * n),
+        "merge_agg": (lambda: T.merge_agg(*inp["partials"]), 32 * n),
         "join_probe": (
             lambda: dp.probe_sorted(inp["uniq"], inp["probe"]), 17 * n
         ),
@@ -85,6 +100,31 @@ def _assert_bitwise_equal(name: str, impl: str, ref, got) -> None:
         assert rv.dtype == gv.dtype and rv.shape == gv.shape and (
             rv.tobytes() == gv.tobytes()
         ), f"{name}[{k}]: {impl} output not bitwise-equal to numpy"
+
+
+# the ops that read the float columns, checked again on subnormal inputs
+_FLOAT_OPS = ("filter", "map", "agg", "merge_agg")
+
+
+def parity_report(n: int, impl: str, seed: int = 7) -> dict[str, str]:
+    """op -> "bitwise-equal" or the first difference, for every op at ``n``
+    rows on ``impl`` against the numpy reference, and as "op+subnormal"
+    for the float ops on inputs that hold subnormal values."""
+    cases = list(_ops(_mk_inputs(int(n), seed)).items())
+    sub = _ops(_mk_inputs(int(n), seed, subnormal=True))
+    cases += [(f"{op}+subnormal", sub[op]) for op in _FLOAT_OPS]
+    out = {}
+    for op_name, (thunk, _) in cases:
+        with dp.use_impl("numpy"):
+            ref = thunk()
+        with dp.use_impl(impl):
+            got = thunk()
+        try:
+            _assert_bitwise_equal(op_name, impl, ref, got)
+            out[op_name] = "bitwise-equal"
+        except AssertionError as e:
+            out[op_name] = f"DIFFERS: {e}"
+    return out
 
 
 def run(quick: bool = False, smoke: bool = False, sizes=None,

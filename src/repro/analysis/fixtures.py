@@ -10,13 +10,15 @@ Bug 1 — fused shape-specialized tanh (batch invariance). The original MAP
 kernel evaluated ``a*1.0001 + tanh(b)`` in one jit unit: XLA contracted the
 mul+add into an FMA and picked shape-dependent tanh approximations, so a
 chunked delta refresh disagreed with a whole-table recompute in the low
-bit. Fix: softsign instead of tanh, split into two jit units
-(``dataplane._jk``'s ``map_mul`` / ``map_add_softsign``).
+bit. Fix: softsign instead of tanh, and the multiply as its own jit unit
+(``dataplane._jk``'s ``map_mul``) with the add and softsign on the host
+(XLA:TPU's float32 division is not correctly rounded).
 
 Bug 2 — ``_filter_mask`` static threshold. The filter compare was jitted
 with its float threshold in ``static_argnums``: every distinct threshold
 value (one per FILTER node) triggered a full retrace. Fix: the threshold
-is traced (``_jk``'s ``cmp``), pinned to the column dtype on the host.
+is traced, pinned to the column dtype on the host. (The compare has since
+left the device: it runs on the host in every impl.)
 
 Forged merge — the MQO hazard ``analysis.mqo_check`` exists to catch
 (DESIGN.md §11): two views whose "shared" FILTER prefix differs only in a
@@ -82,11 +84,10 @@ def legacy_fused_map():
 
 
 def shipped_map_kernels():
-    """The shipped fix: the two separately-jitted softsign kernels."""
+    """The shipped fix: the map's jitted kernels (the multiply alone)."""
     from ..mv.dataplane import _jk
 
-    k = _jk()
-    return k["map_mul"], k["map_add_softsign"]
+    return (_jk()["map_mul"],)
 
 
 def forged_threshold_merge():

@@ -5,50 +5,76 @@ CPU operator inner loops in ``tableops.py``/``partition.py``. This module
 ports those loops to jitted JAX with a Pallas path, behind the same
 ``impl=`` dispatch idiom as ``kernels/ops.py``:
 
-* ``numpy``     — the bitwise REFERENCE and the default: exactly the
-                  vectorized host code the operators always ran. The entire
-                  existing scenario/partition/incremental bitwise matrix
-                  executes on this path unchanged.
+* ``numpy``     — the bitwise REFERENCE: exactly the vectorized host code
+                  the operators always ran, and the data plane off a TPU.
+                  The entire existing scenario/partition/incremental bitwise
+                  matrix executes on this path unchanged.
 * ``xla``       — jitted JAX for the arithmetic passes (splitmix64 hash,
-                  filter compare, map expression, fixed-point encode,
-                  wraparound-exact cumsum segment reduction, sorted-probe);
-                  host numpy for permutations. XLA:CPU's sorts and scatters
+                  the map's multiply, fixed-point encode, sorted-probe);
+                  host numpy for permutations, segment sums
+                  and what is host-only below. XLA:CPU's sorts and scatters
                   are serial — ``jnp.argsort`` loses to numpy's radix sort
                   by ~10x at 1e7 rows — so sorting stays on host where the
                   operators' bitwise contract permits any stable order.
-                  ``"jax"`` is accepted as an alias.
+                  The data plane on a TPU. ``"jax"`` is accepted as an alias.
 * ``pallas``    — Pallas kernels for the element-wise passes (hash +
-                  fused partition histogram, filter compare, the two map
-                  stages, fixed-point encode) and a vectorized binary-search
-                  probe kernel. TARGET path on real TPU pods.
+                  fused partition histogram, the map's multiply,
+                  fixed-point encode) and a vectorized
+                  binary-search probe kernel. The TPU v5e compiler refuses
+                  the 64-bit ones (ROADMAP Speed 2); nothing falls back.
 * ``interpret`` — the Pallas kernels under the interpreter (CPU correctness
                   validation; what the parity tests exercise).
 
 Resolution order: explicit ``impl=`` argument > ``SC_DATAPLANE`` env (read
 ONCE at import; override at runtime with ``set_impl``/``use_impl``) > the
 shared ``kernels.dispatch`` configured impl (``REPRO_KERNEL_IMPL``, so the
-two dispatch layers agree) > ``numpy``.
+two dispatch layers agree) > the platform: ``xla`` when JAX's default
+backend is a TPU, ``numpy`` otherwise.
+
+Host-only on every platform and for every impl — where XLA on a TPU v5e
+does not reproduce numpy bit for bit, or compiles too slowly to use
+(measured; DESIGN.md §9):
+
+* the map's softsign stage ``b / (1 + |b|)``: XLA:TPU's float32 division
+  is not correctly rounded (a third of 1e7 normal draws differ in the last
+  bit), so only the multiply ``a * 1.0001f`` runs on the device;
+* the filter compare: XLA flushes float32 subnormals to zero, on the CPU
+  and on the TPU, so ``x > 0`` is false for a positive subnormal that
+  numpy keeps (and on the chip the device compare took 33x the host's
+  time, transfers included);
+* the map's multiply and fixed-point encode of a column that is not
+  float32: XLA:TPU emulates float64 (its float64 encode differed from
+  numpy in 338,239 of 1e7 rows);
+* the map's multiply of a float32 column that holds a subnormal value
+  (the flush again): the jitted multiply returns a flag with its product,
+  and a flagged column is multiplied on the host. Encode needs no such
+  rule: ``rint(v * 2^16)`` of a subnormal is 0 with or without the flush;
+* ``group_reduce``'s int64 prefix sum: bitwise-equal on the chip, but the
+  v5e compiler takes up to a minute per length bucket for the emulated
+  64-bit scan.
 
 Parity contract — every primitive is bitwise-equal across impls:
 
-* the map expression runs as TWO separately-jitted kernels: XLA:CPU
-  contracts ``a*c + f(b)`` into an FMA inside one fused computation (and
-  ``lax.optimization_barrier`` does not survive fusion), which changes the
-  low bit vs numpy's unfused mul-then-add; splitting the multiply from the
-  add keeps every operation correctly rounded and batch-invariant;
+* the map's multiply and add are separate operations (the add runs on the
+  host): XLA:CPU contracts ``a*c + f(b)`` into an FMA inside one fused
+  computation, which changes the low bit vs numpy's unfused mul-then-add;
 * filter compares are pinned to the column's own dtype (f32 column → f32
-  threshold, f64 → f64, ints compare against f64), so the mask is identical
-  whether or not JAX x64 is enabled and across numpy promotion changes;
+  threshold, f64 → f64, ints compare against f64), so the mask does not
+  move with numpy's promotion rules;
 * AGG sums are int64 fixed-point: int64 addition wraps mod 2^64 identically
-  in ``np.add.at``, host ``cumsum``-diff, and XLA scans, so segment sums
-  over ANY row order inside a group are bitwise-equal — which is what lets
-  the jax path use an unstable host sort for grouping;
+  in ``np.add.at`` and host ``cumsum``-diff, so segment sums over ANY row
+  order inside a group are bitwise-equal — which is what lets the jax path
+  use an unstable host sort for grouping;
+* the jitted kernels take inputs zero-padded to the next power of two
+  (``_pow2_padded``): one compile per size bucket, not per delta or
+  partition length; every kernel is element-wise in its padded input, so
+  padding never reaches a real row;
 * the probe pads its sorted-unique array to the next power of two with
-  int64-max sentinels (bounding jit retraces to one per size bucket); the
-  hit test gathers at the real-length-clipped position, which reproduces
-  the numpy clip semantics even when the probe value equals the sentinel.
+  int64-max sentinels; the hit test gathers at the real-length-clipped
+  position, which reproduces the numpy clip semantics even when the probe
+  value equals the sentinel.
 
-Non-numpy impls require JAX x64 (int64/uint64/float64 table columns); it is
+Non-numpy impls require JAX x64 (int64/uint64 table columns); it is
 enabled lazily, per jitted call, through the exception-safe ``_lazy_x64``
 scope: on success the setting stays enabled (lazy), but a kernel that raises
 restores the prior state — an ``SC_DATAPLANE`` impl switch whose first call
@@ -70,6 +96,7 @@ __all__ = [
     "set_impl",
     "use_impl",
     "resolve_impl",
+    "platform",
     "hash64",
     "partition_ids",
     "partition_index",
@@ -115,7 +142,7 @@ _configured: str = _read_env()
 
 def configured_impl() -> str:
     """The configured data-plane impl ("auto" defers to kernels.dispatch,
-    then numpy). Environment is read once at import."""
+    then the platform). Environment is read once at import."""
     return _configured
 
 
@@ -128,20 +155,29 @@ def set_impl(impl: str | None) -> str:
     return prev
 
 
+@lru_cache(maxsize=1)
+def platform() -> str:
+    """JAX's default backend ("cpu", "tpu", ...), queried once per process."""
+    import jax
+
+    return jax.default_backend()
+
+
 def resolve_impl(impl: str = "auto") -> str:
-    """Resolve a per-call ``impl`` argument to a concrete implementation
-    (pure query — no JAX state is touched)."""
+    """Resolve a per-call ``impl`` argument to a concrete implementation.
+    With nothing configured the platform decides: the jitted ``xla`` path
+    on a TPU, the numpy reference everywhere else."""
     impl = _normalize(impl)
     if impl != "auto":
         return impl
     if _configured != "auto":
         return _configured
     # defer to the shared kernel dispatch so REPRO_KERNEL_IMPL moves both
-    # layers; its own "auto" means "nothing configured" → numpy reference
+    # layers; its own "auto" means "nothing configured"
     shared = _dispatch.kernel_impl()
     if shared != "auto":
         return shared
-    return "numpy"
+    return "xla" if platform() == "tpu" else "numpy"
 
 
 @contextlib.contextmanager
@@ -193,14 +229,27 @@ def _pow2_pad(n: int) -> int:
     return p
 
 
+def _pow2_padded(*arrays: np.ndarray) -> tuple[int, tuple[np.ndarray, ...]]:
+    """Zero-pad same-length 1-D arrays to ``_pow2_pad`` of their length, so
+    a jitted kernel compiles once per size bucket rather than once per
+    distinct delta or partition length. Returns the real length, to slice
+    results back with ``[:n]``."""
+    n = len(arrays[0])
+    L = _pow2_pad(n)
+    if L == n:
+        return n, arrays
+    return n, tuple(np.concatenate([a, np.zeros(L - n, a.dtype)])
+                    for a in arrays)
+
+
 # ---------------------------------------------------------------------------
 # Jitted XLA kernels (built lazily: first non-numpy call pays the traces)
 # ---------------------------------------------------------------------------
 
 @lru_cache(maxsize=None)
 def _jk():
-    """Namespace of jitted XLA kernels. The map expression is deliberately
-    TWO jit units (see module docstring: FMA contraction)."""
+    """Namespace of jitted XLA kernels. The map's multiply is jitted alone
+    (see module docstring: FMA contraction)."""
     import jax
     import jax.numpy as jnp
 
@@ -216,22 +265,19 @@ def _jk():
         return (_hash(k) % np.uint64(P)).astype(jnp.int64)
 
     def _map_mul(a):
-        return a * jnp.float32(1.0001)
-
-    def _map_add_softsign(p, b):
-        return p + b / (jnp.float32(1.0) + jnp.abs(b))
-
-    def _softsign(b):
-        return b / (jnp.float32(1.0) + jnp.abs(b))
+        # with the flag: does the column hold a float32 subnormal (zero
+        # exponent, nonzero mantissa)? XLA flushes those to zero
+        bits = jax.lax.bitcast_convert_type(a, jnp.uint32)
+        sub = ((bits & 0x7F800000) == 0) & ((bits & 0x007FFFFF) != 0)
+        return a * jnp.float32(1.0001), jnp.any(sub)
 
     def _encode(v):
-        return jnp.rint(v.astype(jnp.float64) * AGG_QUANTUM).astype(jnp.int64)
+        # float32 only: v * 2^16 is exact in f32 and so is rint of it, which
+        # gives numpy's float64 result with no float64 on the device
+        return jnp.rint(v * jnp.float32(AGG_QUANTUM)).astype(jnp.int64)
 
     def _encode_w(v, w):
         return _encode(v) * w
-
-    def _cumsum(x):
-        return jnp.cumsum(x)
 
     def _probe(uniq_pad, probe, n_real):
         # n_real is TRACED (a value, not a size): making it static would
@@ -243,27 +289,20 @@ def _jk():
         hit = jnp.take(uniq_pad, posc) == probe
         return hit, posc
 
-    def _cmp(col, thr):
-        return col > thr
-
     ns = {
         "hash": jax.jit(_hash),
         "pid": jax.jit(_pid, static_argnums=1),
         "map_mul": jax.jit(_map_mul),
-        "map_add_softsign": jax.jit(_map_add_softsign),
-        "softsign": jax.jit(_softsign),
         "encode": jax.jit(_encode),
         "encode_w": jax.jit(_encode_w),
-        "cumsum": jax.jit(_cumsum),
         "probe": jax.jit(_probe),
-        "cmp": jax.jit(_cmp),
     }
     return ns
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernels (interpret=True on CPU; same two-stage map split — the
-# interpreter compiles through XLA and has the same FMA hazard)
+# Pallas kernels (interpret=True on CPU; the map's multiply alone, as in
+# _jk — the interpreter compiles through XLA and has the same FMA hazard)
 # ---------------------------------------------------------------------------
 
 _BLOCK = 2048  # 1-D element-wise block; multiple of the (8,128) f32 tile
@@ -361,36 +400,11 @@ def _pk():
         )(nlen, k)
         return np.asarray(pid)[:n], np.asarray(hist)[:P]
 
-    def cmp_kernel_factory(thr, dtype):
-        thr = np.asarray(thr, dtype)
-
-        def kernel(c_ref, o_ref):
-            o_ref[...] = c_ref[...] > thr
-
-        return kernel
-
-    def filter_mask(col, thr, interpret):
-        return _ew_call(cmp_kernel_factory(thr, col.dtype), np.bool_, col,
-                        interpret=interpret)
-
     def map_mul_kernel(a_ref, o_ref):
         o_ref[...] = a_ref[...] * jnp.float32(1.0001)
 
-    def map_add_softsign_kernel(p_ref, b_ref, o_ref):
-        b = b_ref[...]
-        o_ref[...] = p_ref[...] + b / (jnp.float32(1.0) + jnp.abs(b))
-
-    def softsign_kernel(b_ref, o_ref):
-        b = b_ref[...]
-        o_ref[...] = b / (jnp.float32(1.0) + jnp.abs(b))
-
-    def map_derived(a, b, interpret):
-        if b is None:
-            return _ew_call(softsign_kernel, a.dtype, a, interpret=interpret)
-        # two pallas_calls — the unfused mul-then-add contract
-        part = _ew_call(map_mul_kernel, a.dtype, a, interpret=interpret)
-        return _ew_call(map_add_softsign_kernel, a.dtype, part, b,
-                        interpret=interpret)
+    def map_mul(a, interpret):
+        return _ew_call(map_mul_kernel, a.dtype, a, interpret=interpret)
 
     def encode_kernel(v_ref, o_ref):
         v = v_ref[...].astype(jnp.float64)
@@ -449,8 +463,7 @@ def _pk():
     return {
         "hash64": hash64,
         "pid_hist": pid_hist,
-        "filter_mask": filter_mask,
-        "map_derived": map_derived,
+        "map_mul": map_mul,
         "encode": encode,
         "probe": probe,
     }
@@ -481,7 +494,8 @@ def hash64(keys: np.ndarray, impl: str = "auto") -> np.ndarray:
         if impl == "xla":
             # no host-side cast: the kernel's own astype fuses into the jit,
             # saving a full 16B/row round trip over the host arrays
-            return np.asarray(_jk()["hash"](keys))
+            n, (k,) = _pow2_padded(keys)
+            return np.asarray(_jk()["hash"](k))[:n]
         return _pk()["hash64"](keys, interpret=impl == "interpret")
 
 
@@ -497,7 +511,8 @@ def partition_ids(keys: np.ndarray, n_partitions: int,
         return (_hash64_np(keys) % np.uint64(P)).astype(np.int64)
     with _lazy_x64():
         if impl == "xla":
-            return np.asarray(_jk()["pid"](keys, P))
+            n, (k,) = _pow2_padded(keys)
+            return np.asarray(_jk()["pid"](k, P))[:n]
         pid, _ = _pk()["pid_hist"](keys, P, interpret=impl == "interpret")
         return pid
 
@@ -541,26 +556,26 @@ def partition_index(keys: np.ndarray, n_partitions: int,
 
 def _pin_threshold(col: np.ndarray, threshold: float):
     """Compare dtype contract: float columns compare in their own width,
-    everything else against float64 — impl-invariant (independent of the
-    JAX x64 setting and numpy promotion rules)."""
+    everything else against float64 (independent of numpy's promotion
+    rules)."""
     if col.dtype.kind == "f":
         return col.dtype.type(threshold)
     return np.float64(threshold)
 
 
-def filter_mask(col: np.ndarray, threshold: float,
-                impl: str = "auto") -> np.ndarray:
+def filter_mask(col: np.ndarray, threshold: float) -> np.ndarray:
     """Boolean FILTER mask ``col > threshold`` under the pinned-dtype
-    compare contract."""
+    compare contract. Host-only in every impl (module docstring)."""
     col = np.asarray(col)
-    thr = _pin_threshold(col, threshold)
-    impl = resolve_impl(impl)
-    if impl == "numpy" or col.size == 0:
-        return col > thr
-    with _lazy_x64():
-        if impl == "xla":
-            return np.asarray(_jk()["cmp"](col, thr))
-        return _pk()["filter_mask"](col, thr, interpret=impl == "interpret")
+    return col > _pin_threshold(col, threshold)
+
+
+def _has_subnormal(a: np.ndarray) -> bool:
+    """Whether a float32 array holds a subnormal value (zero exponent,
+    nonzero mantissa), which XLA would flush to zero."""
+    bits = a.view(np.uint32)
+    return bool(np.any(((bits & 0x7F800000) == 0)
+                       & ((bits & 0x007FFFFF) != 0)))
 
 
 def map_derived(a: np.ndarray, b: np.ndarray | None,
@@ -569,22 +584,27 @@ def map_derived(a: np.ndarray, b: np.ndarray | None,
     when only one input column exists). Evaluated unfused in every impl —
     each mul/add/div/abs correctly rounded — so the result is bitwise
     independent of batch shape (load-bearing for delta refresh: chunked and
-    whole-table evaluation must agree)."""
+    whole-table evaluation must agree). Only the multiply of a float32
+    column with no subnormal value runs on the device; the rest is
+    host-only (module docstring)."""
     a = np.asarray(a)
     b = None if b is None else np.asarray(b)
     impl = resolve_impl(impl)
-    if impl == "numpy" or a.size == 0:
-        if b is None:
-            return a / (np.float32(1.0) + np.abs(a))
-        return a * np.float32(1.0001) + b / (np.float32(1.0) + np.abs(b))
-    with _lazy_x64():
-        if impl == "xla":
-            k = _jk()
-            if b is None:
-                return np.asarray(k["softsign"](a))
-            # two jit units: XLA would contract the mul into an FMA if fused
-            return np.asarray(k["map_add_softsign"](k["map_mul"](a), b))
-        return _pk()["map_derived"](a, b, interpret=impl == "interpret")
+    if b is None:
+        return a / (np.float32(1.0) + np.abs(a))
+    part = None
+    if impl != "numpy" and a.size and a.dtype == np.float32:
+        with _lazy_x64():
+            if impl == "xla":
+                n, (x,) = _pow2_padded(a)
+                prod, subnormal = _jk()["map_mul"](x)
+                if not subnormal:
+                    part = np.asarray(prod)[:n]
+            elif not _has_subnormal(a):
+                part = _pk()["map_mul"](a, interpret=impl == "interpret")
+    if part is None:  # the reference, and every host-only case
+        part = a * np.float32(1.0001)
+    return part + b / (np.float32(1.0) + np.abs(b))
 
 
 # ---------------------------------------------------------------------------
@@ -594,10 +614,11 @@ def map_derived(a: np.ndarray, b: np.ndarray | None,
 def fixed_point_encode(values: np.ndarray, weights: np.ndarray | None = None,
                        impl: str = "auto") -> np.ndarray:
     """Per-row int64 AGG contribution: ``rint(v * AGG_QUANTUM)`` (times the
-    signed Z-set weight when given). Exact: every later addition is integer."""
+    signed Z-set weight when given). Exact: every later addition is integer.
+    Only float32 values encode on the device (module docstring: host-only)."""
     values = np.asarray(values)
     impl = resolve_impl(impl)
-    if impl == "numpy" or values.size == 0:
+    if impl == "numpy" or values.size == 0 or values.dtype != np.float32:
         fp = np.rint(np.asarray(values, np.float64) * AGG_QUANTUM).astype(
             np.int64
         )
@@ -606,10 +627,10 @@ def fixed_point_encode(values: np.ndarray, weights: np.ndarray | None = None,
         if impl == "xla":
             k = _jk()
             if weights is None:
-                return np.asarray(k["encode"](values))
-            return np.asarray(
-                k["encode_w"](values, np.asarray(weights, np.int64))
-            )
+                n, (v,) = _pow2_padded(values)
+                return np.asarray(k["encode"](v))[:n]
+            n, (v, w) = _pow2_padded(values, np.asarray(weights, np.int64))
+            return np.asarray(k["encode_w"](v, w))[:n]
         return _pk()["encode"](values, weights, interpret=impl == "interpret")
 
 
@@ -649,8 +670,9 @@ def group_reduce(
     in ``tools/sc_lint_baseline.json``.
 
     numpy impl is the reference ``np.unique``+``np.add.at`` loop; the
-    jax/pallas impls encode and scan through jitted kernels around a host
-    sort. Bitwise-equal because the sums are exact integers (mod 2^64) —
+    jax/pallas impls encode through the device kernels and sum segments by
+    a host sort and cumsum (the int64 scan is host-only, module docstring).
+    Bitwise-equal because the sums are exact integers (mod 2^64) —
     independent of both accumulation order and grouping method.
     """
     keys = np.asarray(keys)
@@ -676,8 +698,8 @@ def group_reduce(
                 np.add.at(counts, inv, weights)
         return uniq, sums, counts
     # jitted path: host sort for the grouping permutation (unstable by
-    # default — integer sums commute exactly; see ``stable`` above), jitted
-    # encode + cumsum for the sums
+    # default — integer sums commute exactly; see ``stable`` above), device
+    # encode, host cumsum-diff for the sums
     if stable:
         order = np.argsort(keys, kind="stable")
     else:
@@ -686,20 +708,14 @@ def group_reduce(
     boundary = np.nonzero(sk[1:] != sk[:-1])[0]
     ends = np.concatenate([boundary, [len(sk) - 1]])
     uniq = sk[ends]
-    with _lazy_x64():
-        cum = _jk()["cumsum"]
-        sums = {}
-        for name, (v, kind) in cols.items():
-            contrib = (
-                np.asarray(v, np.int64)
-                if kind == "int"
-                else fixed_point_encode(v, weights, impl=impl)
-            )
-            c = np.asarray(cum(contrib[order]))
-            with np.errstate(over="ignore"):
-                seg = c[ends].copy()
-                seg[1:] -= c[ends[:-1]]
-            sums[name] = seg
+    sums = {}
+    for name, (v, kind) in cols.items():
+        contrib = (
+            np.asarray(v, np.int64)
+            if kind == "int"
+            else fixed_point_encode(v, weights, impl=impl)
+        )
+        sums[name] = _segment_sums_np(contrib[order], ends)
     if weights is None:
         starts = np.concatenate([[0], ends[:-1] + 1])
         counts = (ends - starts + 1).astype(np.int64)
@@ -764,8 +780,9 @@ def probe_sorted(uniq: np.ndarray, probe: np.ndarray,
         uniq_pad = uniq
     with _lazy_x64():
         if impl == "xla":
-            hit, pos = _jk()["probe"](uniq_pad, probe, len(uniq))
-            return np.asarray(hit), np.asarray(pos)
+            n, (pv,) = _pow2_padded(probe)
+            hit, pos = _jk()["probe"](uniq_pad, pv, len(uniq))
+            return np.asarray(hit)[:n], np.asarray(pos)[:n]
         hit, pos = _pk()["probe"](uniq_pad, probe, len(uniq),
                                   interpret=impl == "interpret")
         return hit, pos

@@ -60,6 +60,7 @@ from typing import Any, Sequence
 from ..core.altopt import MultiHostPlan, serial_plan, solve_multihost
 from ..core.speedup import APPENDED, DELTA, STATIC, CostModel
 from ..obs import trace as obs_trace
+from ..runtime import jax_private
 from ..runtime.ft import PreemptionHandler, StragglerDetector
 from . import tableops as T
 from .engine import SubSchedule, _Counters, _RunState
@@ -546,6 +547,14 @@ class HostPool:
     ):
         if backend not in ("process", "thread"):
             raise ValueError(f"unknown backend {backend!r}")
+        if backend == "process" and jax_private.holds_tpu():
+            # one process per chip: this process holds the TPU, so a forked
+            # host worker that reaches a device kernel would fail or hang
+            raise RuntimeError(
+                "HostPool(backend='process') refused: one process per chip. "
+                "This process holds the TPU backend and forked host workers "
+                "cannot use it; run the hosts with backend='thread'."
+            )
         if backend == "process" and "fork" not in mp.get_all_start_methods():
             backend = "thread"  # platforms without fork: closures don't pickle
         self.workload = workload

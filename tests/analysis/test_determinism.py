@@ -53,9 +53,8 @@ def test_legacy_fused_map_fires_transcendental_and_fma():
 def test_shipped_map_kernels_are_quiet():
     pytest.importorskip("jax")
     f32 = np.linspace(-1.0, 1.0, 8, dtype=np.float32)
-    mul, add_softsign = fixtures.shipped_map_kernels()
+    (mul,) = fixtures.shipped_map_kernels()
     assert not gating(lint_jaxpr(mul, f32, symbol="map_mul"))
-    assert not gating(lint_jaxpr(add_softsign, f32, f32, symbol="softsign"))
 
 
 # ---------------------------------------------------------------------------

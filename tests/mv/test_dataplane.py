@@ -267,6 +267,21 @@ def test_env_read_once_and_override_hook(monkeypatch):
         dispatch.set_kernel_impl(prevk)
 
 
+def test_auto_resolves_from_the_platform(monkeypatch):
+    """With nothing configured: numpy reference on CPU, the jitted xla
+    path on a TPU."""
+    monkeypatch.setattr(dp, "_configured", "auto")
+    monkeypatch.setattr(dispatch, "_configured", "auto")
+    assert dp.platform() == "cpu"
+    assert dp.resolve_impl("auto") == "numpy"
+    monkeypatch.setattr(dp, "platform", lambda: "tpu")
+    assert dp.resolve_impl("auto") == "xla"
+    # an explicit impl still wins over the platform
+    assert dp.resolve_impl("numpy") == "numpy"
+    with dp.use_impl("interpret"):
+        assert dp.resolve_impl("auto") == "interpret"
+
+
 def test_use_impl_restores_impl_and_x64():
     import jax
 
@@ -413,6 +428,84 @@ def test_probe_one_trace_per_pow2_bucket():
             assert np.array_equal(hit, ref_hit)
             assert np.array_equal(pos, ref_pos)
         assert kernel._cache_size() - before <= 1
+
+
+def test_pow2_padded_pads_to_the_next_power_of_two():
+    for n in (1, 7, 8, 9, 41, 64, 65, 1000):
+        keys = np.arange(n, dtype=np.int64) + 1
+        w = -keys
+        m, (pk, pw) = dp._pow2_padded(keys, w)
+        assert m == n and len(pk) == len(pw) == dp._pow2_pad(n)
+        assert len(pk) >= max(n, 8) and len(pk) & (len(pk) - 1) == 0
+        assert np.array_equal(pk[:n], keys) and np.array_equal(pw[:n], w)
+        assert not pk[n:].any() and not pw[n:].any()
+        assert pk.dtype == keys.dtype and pw.dtype == w.dtype
+
+
+def test_elementwise_kernel_one_compile_per_size_bucket():
+    """Lengths in one bucket share a compile, and the zero padding never
+    changes a real row's result."""
+    with dp.use_impl("jax"):
+        kernel = dp._jk()["hash"]
+        dp.hash64(np.arange(41, dtype=np.int64))  # bucket 64
+        before = kernel._cache_size()
+        for n in (33, 41, 57, 64):
+            keys = np.arange(n, dtype=np.int64) * 7919 - 3
+            assert np.array_equal(dp.hash64(keys),
+                                  dp.hash64(keys, impl="numpy"))
+        assert kernel._cache_size() == before
+
+
+def _with_subnormals(n, seed):
+    """Standard-normal float32 draws with every fifth value a subnormal of
+    either sign (XLA flushes those to zero)."""
+    rng = np.random.default_rng(seed)
+    a = rng.standard_normal(n).astype(np.float32)
+    tiny = np.finfo(np.float32).tiny
+    sub = (rng.uniform(1e-6, 1.0, n // 5) * tiny).astype(np.float32)
+    a[::5][: len(sub)] = sub * rng.choice(np.float32([-1, 1]), len(sub))
+    assert np.any((a != 0) & (np.abs(a) < tiny))
+    return a
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+def test_subnormal_inputs_stay_bitwise(impl):
+    """Filter, map and AGG encode of float32 columns holding subnormals give
+    the numpy reference's rows and bits on every impl."""
+    a, b = _with_subnormals(3000, 1), _with_subnormals(3000, 2)
+    w = np.resize(np.asarray([-2, -1, 1, 3], np.int64), 3000)
+    ref = (dp.filter_mask(a, 0.0), dp.map_derived(a, b),
+           dp.map_derived(a, None), dp.fixed_point_encode(a, w))
+    with dp.use_impl(impl):
+        got = (dp.filter_mask(a, 0.0), dp.map_derived(a, b),
+               dp.map_derived(a, None), dp.fixed_point_encode(a, w))
+    assert_arrays_bitwise(ref, got, f"subnormal/{impl}")
+
+
+def test_map_multiply_flags_subnormal_columns():
+    """The jitted multiply reports whether its column holds a subnormal, so
+    map_derived multiplies such a column on the host."""
+    a = _with_subnormals(64, 3)
+    with dp.use_impl("jax"), dp._lazy_x64():
+        kernel = dp._jk()["map_mul"]
+        prod, flag = kernel(np.abs(a) + np.float32(1))
+        assert not bool(flag)
+        assert np.array_equal(np.asarray(prod),
+                              (np.abs(a) + np.float32(1)) * np.float32(1.0001))
+        assert bool(kernel(a)[1])
+        assert bool(kernel(np.float32([0.0, -0.0, 1e-45]))[1])
+        assert not bool(kernel(np.float32([0.0, -0.0, np.inf, np.nan]))[1])
+
+
+def test_parity_report_bitwise_with_subnormal_inputs():
+    """The chip smoke's parity gate covers inputs with subnormal values and
+    passes on both jitted paths at a small size."""
+    from benchmarks.tableops_bench import parity_report
+
+    for impl in ("xla", "interpret"):
+        report = parity_report(4096, impl)
+        assert {"map", "map+subnormal", "filter+subnormal"} <= set(report)
+        assert set(report.values()) == {"bitwise-equal"}, (impl, report)
 
 
 def test_group_reduce_stable_flag_bitwise_equal_for_int_sums():

@@ -127,6 +127,20 @@ def test_fault_free_bitwise_process():
     assert not rep.hosts_lost
 
 
+def test_process_hosts_refused_when_this_process_holds_a_tpu(monkeypatch):
+    """One process per chip: forked host workers could not use the TPU the
+    coordinator holds, so the process backend refuses to fork on one."""
+    from repro.runtime import jax_private
+
+    monkeypatch.setattr(jax_private, "holds_tpu", lambda: True)
+    pwl, _ = partition_workload(build_workload(), P)
+    store = DiskStore(tempfile.mkdtemp(prefix="mh-tpu-"))
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        HostPool(pwl, store, [BUDGET], SPECS["insert"], backend="process")
+    pool = HostPool(pwl, store, [BUDGET], SPECS["insert"], backend="thread")
+    pool.shutdown()
+
+
 def test_bytes_placement_matches_hash_bitwise():
     """Placement moves partitions between hosts, never changes their bytes."""
     rep, store = run_mh(7, "insert", 2, backend="thread", placement="bytes")

@@ -179,9 +179,8 @@ def _fixture_findings() -> list[Finding]:
                    "fma-contraction no longer fires on the fused mul+add "
                    "MAP kernel")
     for i, k in enumerate(fixtures.shipped_map_kernels()):
-        args = (f32,) if i == 0 else (f32, f32)
         hits = determinism.lint_jaxpr(
-            k, *args, symbol=f"shipped_map_{i}", path="fixture:shipped_map",
+            k, f32, symbol=f"shipped_map_{i}", path="fixture:shipped_map",
         )
         if gating(hits):
             regression(f"shipped_map_{i}",
