@@ -1,0 +1,12 @@
+"""Host seconds per round of the data plane's device calls: the program's
+``dp.<kernel>`` spans (``mv/dataplane.py``: padding, the jitted call and
+the copy back), summed over threads, over the window's rounds. Read where
+the program records its ``plan`` spans, which came with the ``dp.*``
+ones: there no ``dp.*`` span reads 0 (the numpy data plane)."""
+
+
+def read(obs):
+    if not obs.n_rounds or all(c != "plan" for c, *_ in obs.spans):
+        return None
+    return sum(d for c, _, _, d, _ in obs.spans
+               if c.startswith("dp.")) / obs.n_rounds

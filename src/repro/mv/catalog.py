@@ -15,7 +15,6 @@ import threading
 from typing import Any
 
 from ..obs import trace as obs_trace
-from ..obs.metrics import METRICS
 
 
 class CatalogOverflowError(RuntimeError):
@@ -117,14 +116,12 @@ class MemoryCatalog:
                 if obs_trace.enabled():
                     obs_trace.instant("release", name, size)
                     obs_trace.counter("catalog.bytes", self._used)
-                    METRICS.gauge("catalog_used_bytes", self._used)
 
     # emitted inside put/try_put's critical section; safe because the trace
-    # and metrics locks never call back into the catalog
+    # lock never calls back into the catalog
     def _trace_admit(self, name: str, size: float) -> None:
         obs_trace.instant("admit", name, size)
         obs_trace.counter("catalog.bytes", self._used)
-        METRICS.gauge("catalog_used_bytes", self._used)
 
     def clear(self) -> None:
         """Drop every entry and reset statistics. A reused catalog (the

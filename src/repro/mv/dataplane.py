@@ -80,6 +80,12 @@ scope: on success the setting stays enabled (lazy), but a kernel that raises
 restores the prior state — an ``SC_DATAPLANE`` impl switch whose first call
 fails cannot leak x64 into the f32-default model stack. ``use_impl``
 restores both the impl and the prior x64 setting on exit.
+
+Tracing (``obs.trace``): on the ``xla`` path each device call is one
+``dp.<kernel>`` span — padding, the jitted call and the copy back — whose
+``nbytes`` is the bytes sent plus the bytes returned, and each jitted entry
+records a ``jit.trace`` instant when JAX traces it. The numpy path records
+nothing; with tracing off a call site costs one predicate.
 """
 from __future__ import annotations
 
@@ -90,6 +96,7 @@ from functools import lru_cache
 import numpy as np
 
 from ..kernels import dispatch as _dispatch
+from ..obs import trace as obs_trace
 
 __all__ = [
     "configured_impl",
@@ -246,14 +253,40 @@ def _pow2_padded(*arrays: np.ndarray) -> tuple[int, tuple[np.ndarray, ...]]:
 # Jitted XLA kernels (built lazily: first non-numpy call pays the traces)
 # ---------------------------------------------------------------------------
 
+def _nbytes(*arrays) -> float:
+    """Bytes of host and device arrays: what a device call sends plus what
+    it returns (a ``dp.<kernel>`` span's ``nbytes``)."""
+    return float(sum(a.nbytes for a in arrays))
+
+
+# span category of each kernel's device calls (no string built per call)
+_SPAN = {k: f"dp.{k}" for k in ("hash", "pid", "encode", "encode_w")}
+
+
+def _on_device(kernel: str, cols: tuple, *static) -> np.ndarray:
+    """One call of ``_jk()[kernel]`` on same-length ``cols`` zero-padded to
+    their size bucket (then ``static``), its output copied back and sliced
+    to the real length: one ``dp.<kernel>`` span."""
+    with obs_trace.span(_SPAN[kernel], "") as sp:
+        n, padded = _pow2_padded(*cols)
+        out = _jk()[kernel](*padded, *static)
+        host = np.asarray(out)[:n]
+        if obs_trace.enabled():
+            sp.set(_nbytes(*padded, out))
+    return host
+
+
 @lru_cache(maxsize=None)
 def _jk():
     """Namespace of jitted XLA kernels. The map's multiply is jitted alone
-    (see module docstring: FMA contraction)."""
+    (see module docstring: FMA contraction). Each jitted entry records a
+    ``jit.trace`` instant when JAX traces it, which is never in steady
+    state; the shared bodies (``_splitmix``, ``_fixed``) record none, so
+    one trace counts once."""
     import jax
     import jax.numpy as jnp
 
-    def _hash(k):
+    def _splitmix(k):
         x = k.astype(jnp.uint64)
         x = x ^ (x >> np.uint64(30))
         x = x * np.uint64(_SPLITMIX_C1)
@@ -261,10 +294,21 @@ def _jk():
         x = x * np.uint64(_SPLITMIX_C2)
         return x ^ (x >> np.uint64(31))
 
+    def _fixed(v):
+        # float32 only: v * 2^16 is exact in f32 and so is rint of it, which
+        # gives numpy's float64 result with no float64 on the device
+        return jnp.rint(v * jnp.float32(AGG_QUANTUM)).astype(jnp.int64)
+
+    def _hash(k):
+        obs_trace.instant("jit.trace", "dp.hash")
+        return _splitmix(k)
+
     def _pid(k, P):
-        return (_hash(k) % np.uint64(P)).astype(jnp.int64)
+        obs_trace.instant("jit.trace", "dp.pid")
+        return (_splitmix(k) % np.uint64(P)).astype(jnp.int64)
 
     def _map_mul(a):
+        obs_trace.instant("jit.trace", "dp.map_mul")
         # with the flag: does the column hold a float32 subnormal (zero
         # exponent, nonzero mantissa)? XLA flushes those to zero
         bits = jax.lax.bitcast_convert_type(a, jnp.uint32)
@@ -272,14 +316,15 @@ def _jk():
         return a * jnp.float32(1.0001), jnp.any(sub)
 
     def _encode(v):
-        # float32 only: v * 2^16 is exact in f32 and so is rint of it, which
-        # gives numpy's float64 result with no float64 on the device
-        return jnp.rint(v * jnp.float32(AGG_QUANTUM)).astype(jnp.int64)
+        obs_trace.instant("jit.trace", "dp.encode")
+        return _fixed(v)
 
     def _encode_w(v, w):
-        return _encode(v) * w
+        obs_trace.instant("jit.trace", "dp.encode_w")
+        return _fixed(v) * w
 
     def _probe(uniq_pad, probe, n_real):
+        obs_trace.instant("jit.trace", "dp.probe")
         # n_real is TRACED (a value, not a size): making it static would
         # retrace once per distinct unique-key count, defeating the pow2
         # padding's one-trace-per-size-bucket contract (sc-lint's
@@ -494,8 +539,7 @@ def hash64(keys: np.ndarray, impl: str = "auto") -> np.ndarray:
         if impl == "xla":
             # no host-side cast: the kernel's own astype fuses into the jit,
             # saving a full 16B/row round trip over the host arrays
-            n, (k,) = _pow2_padded(keys)
-            return np.asarray(_jk()["hash"](k))[:n]
+            return _on_device("hash", (keys,))
         return _pk()["hash64"](keys, interpret=impl == "interpret")
 
 
@@ -511,8 +555,7 @@ def partition_ids(keys: np.ndarray, n_partitions: int,
         return (_hash64_np(keys) % np.uint64(P)).astype(np.int64)
     with _lazy_x64():
         if impl == "xla":
-            n, (k,) = _pow2_padded(keys)
-            return np.asarray(_jk()["pid"](k, P))[:n]
+            return _on_device("pid", (keys,), P)
         pid, _ = _pk()["pid_hist"](keys, P, interpret=impl == "interpret")
         return pid
 
@@ -596,10 +639,14 @@ def map_derived(a: np.ndarray, b: np.ndarray | None,
     if impl != "numpy" and a.size and a.dtype == np.float32:
         with _lazy_x64():
             if impl == "xla":
-                n, (x,) = _pow2_padded(a)
-                prod, subnormal = _jk()["map_mul"](x)
-                if not subnormal:
-                    part = np.asarray(prod)[:n]
+                with obs_trace.span("dp.map_mul", "") as sp:
+                    n, (x,) = _pow2_padded(a)
+                    prod, subnormal = _jk()["map_mul"](x)
+                    if not subnormal:
+                        part = np.asarray(prod)[:n]
+                    if obs_trace.enabled():
+                        sp.set(_nbytes(x, subnormal)
+                               + (0.0 if part is None else prod.nbytes))
             elif not _has_subnormal(a):
                 part = _pk()["map_mul"](a, interpret=impl == "interpret")
     if part is None:  # the reference, and every host-only case
@@ -625,12 +672,10 @@ def fixed_point_encode(values: np.ndarray, weights: np.ndarray | None = None,
         return fp if weights is None else fp * weights
     with _lazy_x64():
         if impl == "xla":
-            k = _jk()
             if weights is None:
-                n, (v,) = _pow2_padded(values)
-                return np.asarray(k["encode"](v))[:n]
-            n, (v, w) = _pow2_padded(values, np.asarray(weights, np.int64))
-            return np.asarray(k["encode_w"](v, w))[:n]
+                return _on_device("encode", (values,))
+            return _on_device("encode_w",
+                              (values, np.asarray(weights, np.int64)))
         return _pk()["encode"](values, weights, interpret=impl == "interpret")
 
 
@@ -750,6 +795,18 @@ def first_occurrence(keys: np.ndarray,
     return sk[sel], order[sel]
 
 
+def _sentinel_padded(uniq: np.ndarray) -> np.ndarray:
+    """The probe index padded to a power of two with int64-max sentinels:
+    one trace per size bucket. Sentinels sort after every real key, so
+    positions for probe < I64MAX are unchanged; the hit test gathers at the
+    real-clipped position, reproducing numpy clip semantics even for
+    probe == I64MAX."""
+    L = _pow2_pad(len(uniq))
+    if L == len(uniq):
+        return uniq
+    return np.concatenate([uniq, np.full(L - len(uniq), _I64MAX, uniq.dtype)])
+
+
 def probe_sorted(uniq: np.ndarray, probe: np.ndarray,
                  impl: str = "auto") -> tuple[np.ndarray, np.ndarray]:
     """Probe sorted-unique ``uniq`` with ``probe`` values: ``(hit, pos)``
@@ -766,23 +823,16 @@ def probe_sorted(uniq: np.ndarray, probe: np.ndarray,
         pos = np.searchsorted(uniq, probe)
         posc = np.clip(pos, 0, len(uniq) - 1)
         return uniq[posc] == probe, posc
-    # pad the index to a power of two with int64-max sentinels: one trace
-    # per size bucket. Sentinels sort after every real key, so positions
-    # for probe < I64MAX are unchanged; the hit test gathers at the
-    # real-clipped position, reproducing numpy clip semantics even for
-    # probe == I64MAX.
-    L = _pow2_pad(len(uniq))
-    if L != len(uniq):
-        uniq_pad = np.concatenate(
-            [uniq, np.full(L - len(uniq), _I64MAX, uniq.dtype)]
-        )
-    else:
-        uniq_pad = uniq
     with _lazy_x64():
         if impl == "xla":
-            n, (pv,) = _pow2_padded(probe)
-            hit, pos = _jk()["probe"](uniq_pad, pv, len(uniq))
-            return np.asarray(hit)[:n], np.asarray(pos)[:n]
-        hit, pos = _pk()["probe"](uniq_pad, probe, len(uniq),
+            with obs_trace.span("dp.probe", "") as sp:
+                uniq_pad = _sentinel_padded(uniq)
+                n, (pv,) = _pow2_padded(probe)
+                hit, pos = _jk()["probe"](uniq_pad, pv, len(uniq))
+                out = np.asarray(hit)[:n], np.asarray(pos)[:n]
+                if obs_trace.enabled():
+                    sp.set(_nbytes(uniq_pad, pv, hit, pos))
+            return out
+        hit, pos = _pk()["probe"](_sentinel_padded(uniq), probe, len(uniq),
                                   interpret=impl == "interpret")
         return hit, pos

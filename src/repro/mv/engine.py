@@ -497,11 +497,9 @@ class ThreadedEngine:
             obs_trace.record(
                 "round", f"round{round_idx}", tr0, obs_trace.now() - tr0
             )
-            METRICS.observe("round_wall_s", elapsed)
             for name, es in stats.entry_stats().items():
                 METRICS.inc("catalog_hits", es["hits"], entry=name)
                 METRICS.inc("catalog_misses", es["misses"], entry=name)
-                METRICS.inc("catalog_overflow", es["overflow"], entry=name)
         return RunReport(
             elapsed=elapsed,
             peak_catalog_bytes=self.catalog.peak_bytes,

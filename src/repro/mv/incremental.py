@@ -565,28 +565,39 @@ def run_scenario(
     rounds: list[RoundReport] = []
     fb_ewma = FallbackRateEwma()  # observed fallback-rate estimator
     for r in range(spec.n_rounds + 1):
+        # every span of round r's plan stretch carries r (engine.run sets
+        # it again for callers that run it directly)
+        obs_trace.set_round(r)
         rate_used = fb_ewma.rate
-        # manifest sizes already include all growth up to round r-1; the
-        # JOIN correction term uses the EWMA of the per-round fallback
-        # rates observed so far (1.0 until the first observation) — a
-        # single churn spike decays instead of biasing every later round
-        # the way a cumulative ratio would (round_view).
-        view, sizes, force_full = round_view(
-            workload, spec, cost_model, r, store=store,
-            fallback_rate=rate_used,
-        )
-        g = view.to_graph(cost_model)
-        if not optimize:
-            plan = serial_plan(g)
-        elif solve_fn is not None:
-            plan = solve_fn(g, budget_bytes, n_compute_workers)
-        else:
-            plan = solve(g, budget=budget_bytes, n_workers=n_compute_workers)
-        statuses = view.meta.get("update", {}).get("statuses", ())
-        static = frozenset(i for i, s in enumerate(statuses) if s == STATIC)
-        if static_fn is not None:
-            static = static | frozenset(static_fn(r, static))
-        engine.configure_round(r, sorted(static), sorted(force_full))
+        with obs_trace.span("plan", workload.name):
+            # manifest sizes already include all growth up to round r-1;
+            # the JOIN correction term uses the EWMA of the per-round
+            # fallback rates observed so far (1.0 until the first
+            # observation) — a single churn spike decays instead of biasing
+            # every later round the way a cumulative ratio would
+            # (round_view).
+            with obs_trace.span("plan.view", workload.name):
+                view, sizes, force_full = round_view(
+                    workload, spec, cost_model, r, store=store,
+                    fallback_rate=rate_used,
+                )
+                g = view.to_graph(cost_model)
+            with obs_trace.span("plan.solve", workload.name):
+                if not optimize:
+                    plan = serial_plan(g)
+                elif solve_fn is not None:
+                    plan = solve_fn(g, budget_bytes, n_compute_workers)
+                else:
+                    plan = solve(g, budget=budget_bytes,
+                                 n_workers=n_compute_workers)
+            statuses = view.meta.get("update", {}).get("statuses", ())
+            static = frozenset(
+                i for i, s in enumerate(statuses) if s == STATIC
+            )
+            if static_fn is not None:
+                with obs_trace.span("plan.prune", workload.name):
+                    static = static | frozenset(static_fn(r, static))
+            engine.configure_round(r, sorted(static), sorted(force_full))
         rep = engine.run(plan)
         fb_ewma.observe(engine.fb_affected, engine.fb_matched)
         rounds.append(

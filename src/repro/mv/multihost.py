@@ -1118,23 +1118,27 @@ def run_multihost_scenario(
         fb_ewma = FallbackRateEwma()
         rounds: list[MultiHostRoundReport] = []
         for r in range(spec.n_rounds + 1):
-            view, sizes, force_full = round_view(
-                pwl, espec, cost_model, r, store=store,
-                fallback_rate=fb_ewma.rate,
-            )
-            g = view.to_graph(cost_model)
-            if optimize:
-                plan = solve_multihost(
-                    g, budgets, P, placement=placement_t,
-                    n_workers=n_workers_per_host, **(solve_kw or {}),
+            # the plan stretch's spans (scan routing among them) carry
+            # the round they plan, inside the round's frame
+            obs_trace.set_round(r)
+            with obs_trace.span("plan", pwl.name):
+                view, sizes, force_full = round_view(
+                    pwl, espec, cost_model, r, store=store,
+                    fallback_rate=fb_ewma.rate,
                 )
-            else:
-                plan = _serial_multihost(g, budgets, P, placement_t)
-            statuses = view.meta.get("update", {}).get("statuses", ())
-            static = frozenset(
-                i for i, s in enumerate(statuses) if s == STATIC
-            )
-            static = static | frozenset(static_fn(r, static))
+                g = view.to_graph(cost_model)
+                if optimize:
+                    plan = solve_multihost(
+                        g, budgets, P, placement=placement_t,
+                        n_workers=n_workers_per_host, **(solve_kw or {}),
+                    )
+                else:
+                    plan = _serial_multihost(g, budgets, P, placement_t)
+                statuses = view.meta.get("update", {}).get("statuses", ())
+                static = frozenset(
+                    i for i, s in enumerate(statuses) if s == STATIC
+                )
+                static = static | frozenset(static_fn(r, static))
             rep = pool.run_round(
                 r, plan, static=sorted(static),
                 force_full=sorted(force_full), sizes=sizes,

@@ -57,6 +57,7 @@ import numpy as np
 
 from ..core.graph import normalize_shares
 from ..core.speedup import CostModel
+from ..obs import trace as obs_trace
 from . import dataplane
 from . import tableops as T
 from .storage import DiskStore, PARTITION_SEP, partition_entry_name
@@ -188,12 +189,15 @@ class _ScanRouter:
     is computed once per (round, churn-spec) and memoized for the current
     round, so a P-way scan costs one delta replay and one hash pass instead
     of P. Thread-safe — partition nodes of one scan execute on different
-    workers."""
+    workers. A memo miss is traced as an ``ingest.route`` span (generation
+    and routing) around an ``ingest.source`` span (the scan's own
+    function); a hit records nothing."""
 
-    def __init__(self, orig_fn, orig_delta, P: int):
+    def __init__(self, orig_fn, orig_delta, P: int, name: str = ""):
         self._fn = orig_fn
         self._delta = orig_delta
         self.P = P
+        self.name = name
         self._lock = threading.Lock()
         self._key = None
         self._parts: list[T.Table] | None = None
@@ -207,7 +211,10 @@ class _ScanRouter:
     def _routed(self, key, produce) -> list[T.Table]:
         with self._lock:
             if self._key != key:
-                self._parts = partition_table(produce(), self.P)
+                with obs_trace.span("ingest.route", self.name):
+                    with obs_trace.span("ingest.source", self.name):
+                        table = produce()
+                    self._parts = partition_table(table, self.P)
                 self._key = key
             return self._parts
 
@@ -258,7 +265,7 @@ def partition_workload(
     nodes: list[MVNode] = []
     for v, n in enumerate(workload.nodes):
         router = (
-            _ScanRouter(n.fn, n.delta_fn, P)
+            _ScanRouter(n.fn, n.delta_fn, P, n.name)
             if not n.parents and (n.fn is not None or n.delta_fn is not None)
             else None
         )

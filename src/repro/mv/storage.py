@@ -234,8 +234,6 @@ class DiskStore:
                 if residual > 0:
                     with obs_trace.span("stall.write", name):
                         time.sleep(residual)
-                    if obs_trace.enabled():
-                        METRICS.inc("stall_seconds.write", residual, entry=name)
             dt = time.perf_counter() - t0
         if obs_trace.enabled():
             METRICS.inc("bytes_written", nbytes, entry=name)
@@ -343,8 +341,6 @@ class DiskStore:
             if residual > 0:
                 with obs_trace.span("stall.read", name):
                     time.sleep(residual)
-                if obs_trace.enabled():
-                    METRICS.inc("stall_seconds.read", residual, entry=name)
 
     def read(self, name: str) -> dict[str, np.ndarray]:
         return self.read_parts(name)

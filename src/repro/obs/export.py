@@ -76,7 +76,7 @@ def to_chrome_trace(spans: Sequence[Span]) -> dict[str, Any]:
                 "name": s.name, "ph": "C", "pid": pid, "tid": 0,
                 "ts": ts, "args": {"bytes": s.value},
             })
-        elif s.dur == 0.0 and s.cat in ("admit", "release"):
+        elif s.dur == 0.0 and s.cat in ("admit", "release", "jit.trace"):
             events.append({
                 "name": f"{s.cat}:{s.name}", "cat": s.cat, "ph": "i",
                 "pid": pid, "tid": tid(s.track, s.worker), "ts": ts,
@@ -102,8 +102,10 @@ def validate_chrome_trace(doc: dict[str, Any]) -> list[str]:
     """Structural checks on an exported trace document; returns the list of
     problems (empty = valid). Checked: the event array exists, every event
     has name/ph/pid, timed events have non-negative ts and dur, and every
-    keyed (args.round >= 0) X/i event lies within its (pid, round) frame
-    span — 'spans nest within rounds'."""
+    keyed (args.round >= 0) X/i event lies within its (pid, round) frame —
+    'spans nest within rounds'. A round's frame runs from its ``plan`` span
+    (the scenario's stretch before the engine run), where there is one, to
+    the end of its ``round`` span."""
     problems: list[str] = []
     events = doc.get("traceEvents")
     if not isinstance(events, list) or not events:
@@ -121,9 +123,10 @@ def validate_chrome_trace(doc: dict[str, Any]) -> list[str]:
             dur = e.get("dur", -1.0)
             if not isinstance(dur, (int, float)) or dur < 0:
                 problems.append(f"negative/missing dur: {e.get('name')}")
-            if e.get("cat") == "round":
+            if e.get("cat") in ("round", "plan"):
                 key = (e["pid"], e.get("args", {}).get("round", -1))
-                frames[key] = (e["ts"], e["ts"] + e["dur"])
+                lo, hi = frames.get(key, (e["ts"], e["ts"] + e["dur"]))
+                frames[key] = (min(lo, e["ts"]), max(hi, e["ts"] + e["dur"]))
     eps = 1.0  # µs of clock skew tolerated at frame edges
     for e in events:
         if e.get("ph") not in ("X", "i") or e.get("cat") in ("round", None):

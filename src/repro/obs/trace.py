@@ -26,7 +26,25 @@ Span categories (the shared vocabulary; dotted suffixes refine a family):
 ``admit`` ``release``     Memory Catalog entry lifecycle (instant events)
 ``catalog.bytes``         catalog occupancy counter samples
 ``round``                 one engine run / one simulated round (the frame
-                          every other span of that run nests inside)
+                          every span of that run nests inside)
+``plan``                  a scenario round's stretch before its engine run
+                          (``incremental.run_scenario``): the round's view,
+                          its solve, its partition pruning; with ``round``
+                          it frames the whole round
+``plan.view``             the round's refresh view and its graph
+``plan.solve``            the round's planner solve
+``plan.prune``            the clean-partition pruner (``static_fn``)
+``ingest.route``          a scan's output generated and hash-routed to its
+                          P partitions (``partition._ScanRouter``, once per
+                          round and scan; nested in ``plan.prune`` or a
+                          ``compute`` span)
+``ingest.source``         the scan's own ``fn``/``delta_fn`` inside it
+``dp.<kernel>``           one device call of the ``xla`` data plane
+                          (``hash``, ``pid``, ``map_mul``, ``encode``,
+                          ``encode_w``, ``probe``): padding, the call and
+                          the copy back; ``nbytes`` = bytes sent + returned
+``jit.trace``             a data-plane kernel traced by JAX (instant, named
+                          ``dp.<kernel>``): in steady state there are none
 ``redispatch``            a task moved off a lost/straggling host by the
                           multi-host coordinator (instant, on the receiving
                           host's track)
@@ -35,7 +53,8 @@ Span categories (the shared vocabulary; dotted suffixes refine a family):
 Every span is keyed by ``(mv, partition, round, worker)``: ``mv``/
 ``partition`` are derived from the store entry name (``mv3@p2`` →
 ``("mv3", 2)``; unpartitioned → partition ``-1``), ``round`` comes from the
-process-wide context (set by the scenario drivers via ``set_round``), and
+process-wide context (set by the scenario loops via ``set_round`` at the
+top of each round, so a round's plan stretch carries the round it plans), and
 ``worker`` is the recording thread (real) or the virtual channel (sim).
 
 Overhead contract: recording is a flag check plus one lock-guarded list
@@ -52,7 +71,7 @@ from __future__ import annotations
 import os
 import threading
 import time
-from typing import Iterable, NamedTuple
+from typing import NamedTuple
 
 __all__ = [
     "Span",
@@ -273,25 +292,3 @@ def span(cat: str, name: str, nbytes: float = 0.0):
     if not _enabled:
         return _NULL
     return _SpanCtx(cat, name, nbytes)
-
-
-def filter_spans(
-    items: Iterable[Span],
-    cat: str | None = None,
-    track: str | None = None,
-    round_idx: int | None = None,
-    mv: str | None = None,
-) -> list[Span]:
-    """Convenience filter used by the audit/export layers and tests."""
-    out = []
-    for s in items:
-        if cat is not None and not s.cat.startswith(cat):
-            continue
-        if track is not None and s.track != track:
-            continue
-        if round_idx is not None and s.round != round_idx:
-            continue
-        if mv is not None and s.mv != mv:
-            continue
-        out.append(s)
-    return out

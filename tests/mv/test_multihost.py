@@ -242,6 +242,33 @@ def test_redispatch_visible_in_trace_spans():
     assert all(s.worker == "coord" for s in rd)
 
 
+def test_plan_stretch_spans_carry_the_round_they_plan():
+    """The coordinator's plan stretch (solve, clean-partition pruning and
+    the scan routing it triggers) is a ``plan`` span stamped with the round
+    it plans, ahead of that round's frame."""
+    was = obs_trace.enabled()
+    obs_trace.enable(True)
+    obs_trace.clear()
+    try:
+        rep, store = run_mh(7, "insert", 2, backend="thread")
+        spans = [s for s in obs_trace.drain() if s.track == "real"]
+    finally:
+        obs_trace.enable(was)
+    assert_matches_reference(store, 7, "insert")
+    prev_end = -1.0
+    for rnd in rep.rounds:
+        r = rnd.round_idx
+        (plan,) = [s for s in spans if s.cat == "plan" and s.round == r]
+        (frame,) = [s for s in spans if s.cat == "round" and s.round == r]
+        assert prev_end <= plan.ts <= plan.ts + plan.dur <= frame.ts + 1e-6
+        prev_end = frame.ts + frame.dur
+    routed = [s for s in spans if s.cat == "ingest.route" and s.round > 0]
+    assert routed
+    for s in routed:
+        (plan,) = [p for p in spans if p.cat == "plan" and p.round == s.round]
+        assert plan.ts <= s.ts and s.ts + s.dur <= plan.ts + plan.dur + 1e-6
+
+
 def test_all_hosts_lost_raises():
     fp = FaultPlan((
         FaultAction("kill", host=0, round_idx=1, after_tasks=0),

@@ -16,7 +16,9 @@ Covers the §12 contracts:
   drift rows with sane accounting.
 """
 import json
+import tracemalloc
 
+import numpy as np
 import pytest
 
 from repro.core import CostModel, solve
@@ -27,11 +29,13 @@ from repro.mv import (
     calibrate_sizes,
     generate_workload,
     realize_workload,
+    run_partitioned_scenario,
     run_scenario,
     simulate,
     simulate_scenario,
     verify_scenario_equivalence,
 )
+from repro.mv import dataplane
 from repro.obs import METRICS, MetricsRegistry, trace as tr
 from repro.obs.audit import audit_scenario
 from repro.obs.export import (
@@ -89,6 +93,26 @@ def test_disabled_fast_path_is_allocation_free_and_silent():
     assert tr.drain() == []
 
 
+def test_disabled_dataplane_call_is_allocation_free():
+    """With tracing off the ``dp.*`` call sites get the shared null context
+    and the recorder allocates nothing, on the path that would record."""
+    keys = np.arange(100, dtype=np.int64)
+    uniq = np.arange(0, 200, 3, dtype=np.int64)
+    with dataplane.use_impl("xla"):
+        want = (dataplane.hash64(keys), dataplane.probe_sorted(uniq, keys))
+        tracemalloc.start()
+        try:
+            got = (dataplane.hash64(keys), dataplane.probe_sorted(uniq, keys))
+            snap = tracemalloc.take_snapshot()
+        finally:
+            tracemalloc.stop()
+    assert np.array_equal(got[0], want[0])
+    assert all(np.array_equal(a, b) for a, b in zip(got[1], want[1]))
+    in_trace = snap.filter_traces([tracemalloc.Filter(True, tr.__file__)])
+    assert in_trace.statistics("lineno") == []
+    assert tr.drain() == []
+
+
 def test_enabled_recording_round_context_and_entry_parsing():
     tr.enable(True)
     tr.set_round(7)
@@ -114,21 +138,23 @@ def test_sim_offset_accumulates_and_resets_on_clear():
 
 
 def test_metrics_registry_counters_gauges_histograms(tmp_path):
+    """The registry keeps counters only: occupancy and round walls are
+    read from the ``catalog.bytes`` samples and the ``round`` spans."""
     m = MetricsRegistry()
     m.inc("bytes_read", 100.0, entry="mv1")
     m.inc("bytes_read", 50.0, entry="mv1")
     m.inc("bytes_read", 10.0, entry="mv2")
-    m.gauge("catalog_used_bytes", 77.0)
-    m.observe("round_wall_s", 0.5)
-    m.observe("round_wall_s", 2.0)
+    m.inc("join_fallbacks")
     assert m.counter_value("bytes_read", "mv1") == 150.0
+    assert m.counter_value("join_fallbacks") == 1.0
     assert m.counter_family("bytes_read") == {"mv1": 150.0, "mv2": 10.0}
     snap = m.snapshot()
-    assert snap["gauges"]["catalog_used_bytes"][""] == 77.0
-    h = snap["histograms"]["round_wall_s"][""]
-    assert h["count"] == 2 and h["min"] == 0.5 and h["max"] == 2.0
+    assert set(snap) == {"counters"}
+    assert not hasattr(m, "gauge") and not hasattr(m, "observe")
     p = m.export_json(tmp_path / "metrics.json")
     assert json.loads(p.read_text())["counters"]["bytes_read"]["mv1"] == 150.0
+    m.clear()
+    assert m.snapshot() == {"counters": {}}
 
 
 # ---------------------------------------------------------------------------
@@ -356,4 +382,73 @@ def test_traced_scenario_metrics_fold_per_entry(tmp_path):
     )
     assert sum(snap["counters"].get("catalog_hits", {}).values()) == total_hits
     assert sum(snap["counters"]["bytes_written"].values()) > 0
-    assert snap["histograms"]["round_wall_s"][""]["count"] == len(rep.rounds)
+    # one engine ``round`` span per round, stamped with its round
+    frames = [s.round for s in tr.spans() if s.cat == "round"]
+    assert sorted(frames) == [r.round_idx for r in rep.rounds]
+
+
+def test_traced_partitioned_scenario_spans_plan_ingest_and_dataplane(tmp_path):
+    """A traced P=2 scenario on the ``xla`` data plane: every round's plan
+    stretch (view, solve, prune), scan routing and device calls are spans
+    stamped with the round they serve, device calls nest in a ``compute``
+    or ``ingest.route`` span of their own thread, and the export still
+    validates (a round's frame is its plan span plus its engine run)."""
+    wl = build(tmp_path)
+    n_rounds = 2
+    spec = UpdateSpec(mode="incremental", n_rounds=n_rounds, ingest_frac=0.2,
+                      update_frac=0.05)
+    budget = sum(n.size for n in wl.nodes) * 0.5
+
+    tr.enable(True)
+    with dataplane.use_impl("xla"):
+        run_partitioned_scenario(wl, 2, DiskStore(tmp_path / "run"), budget,
+                                 spec, CM, n_compute_workers=2)
+    spans = tr.drain()
+    tr.enable(False)
+
+    cats = {s.cat for s in spans}
+    assert {"plan", "plan.view", "plan.solve", "plan.prune", "ingest.route",
+            "ingest.source", "round"} <= cats
+    dp = [s for s in spans if s.cat.startswith("dp.")]
+    assert {s.cat for s in dp} >= {"dp.pid", "dp.probe"}
+    assert all(s.nbytes > 0 for s in dp)
+    traces = [s for s in spans if s.cat == "jit.trace"]
+    assert traces and all(s.dur == 0.0 and s.name.startswith("dp.")
+                          for s in traces)
+
+    def only(cat, r):
+        found = [s for s in spans if s.cat == cat and s.round == r]
+        assert len(found) == 1, (cat, r, found)
+        return found[0]
+
+    def inside(child, parent):
+        return (parent.ts - 1e-6 <= child.ts
+                and child.ts + child.dur <= parent.ts + parent.dur + 1e-6)
+
+    prev_end = -1.0
+    for r in range(n_rounds + 1):
+        plan, frame = only("plan", r), only("round", r)
+        # round r's plan runs after round r - 1's engine run and before its
+        # own: the stamp is the round it plans, not the previous one
+        assert prev_end <= plan.ts
+        assert plan.ts + plan.dur <= frame.ts + 1e-6
+        for cat in ("plan.view", "plan.solve", "plan.prune"):
+            assert inside(only(cat, r), plan)
+        prev_end = frame.ts + frame.dur
+
+    def parents(s, cats_):
+        return [p for p in spans if p.cat in cats_ and p.worker == s.worker
+                and p.round == s.round and p is not s and inside(s, p)]
+
+    routes = [s for s in spans if s.cat == "ingest.route"]
+    assert {s.round for s in routes} == set(range(n_rounds + 1))
+    for s in routes:
+        assert parents(s, {"plan.prune", "compute"}), s
+    for s in spans:
+        if s.cat == "ingest.source":
+            assert parents(s, {"ingest.route"}), s
+    for s in dp:
+        assert parents(s, {"compute", "ingest.route"}), s
+
+    assert validate_chrome_trace(to_chrome_trace(spans)) == []
+
