@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import random
+from bisect import bisect_left
 from typing import Callable, Sequence
 
 from .graph import MVGraph
@@ -115,6 +116,33 @@ def branch_and_bound_mkp(
     DFS over items sorted by profit density, with an upper bound from the
     fractional relaxation of the single tightest constraint (dropping all
     other constraints only increases the optimum, so the bound is valid).
+
+    The bound at position ``k`` of the density order costs what the
+    tightest constraint costs, not a walk over every remaining item:
+
+    * **Tightest constraint** — the first index of the smallest remaining
+      capacity, ``caps.index(min(caps))`` (ties to the lowest index), taken
+      afresh only after an include or undo changed ``caps``. ``caps`` moves
+      by the same ``-= w`` / ``+= w`` steps as ever, so its floats, and the
+      constraint chosen, are those of a per-visit scan.
+    * **Relaxation from k** — precomputed once per call, each constraint
+      holds its items' positions in the density order (ascending), their
+      weights and a suffix sum of their integer profits. Every item outside
+      the constraint counts whole, so the bound is ``cur + suffix[k] -
+      csuf[j]`` plus the fractional part of item ``j``: the greedy fill walks
+      the constraint's items from ``bisect_left(positions, k)`` and stops at
+      the first ``j`` that does not fit. The generic ``cur + suffix[k]`` is
+      tested first, since ``min(ub, generic) <= best`` holds iff either does.
+    * **Exactness** — the integer part ``ub`` is an exact Python int and
+      the fraction ``f`` the same float as a per-item walk computes. That
+      walk adds ``f`` in the middle of the integer sum and rounds once per
+      later addition (at most ``n + 1`` roundings), so its float lies
+      within ``(n + 2)·(ub + |f| + 1)·2⁻⁵³`` of the exact ``ub + f``.
+      Where ``ub + f - best`` lies farther than twice that from 0 (and
+      every integer is below 2⁵², exact as a float), its sign decides
+      ``bound <= best`` exactly as the walk's float would. Inside that
+      band the walk's own order of addition is replayed, so the test never
+      flips and the search visits the same nodes in the same order.
     """
     # Integer-round profits (paper footnote 3) for the search; keep >=1 for
     # any strictly positive score so rounding never erases a benefit.
@@ -124,6 +152,7 @@ def branch_and_bound_mkp(
     order = sorted(
         items, key=lambda i: (-(iprof[i] / max(weights[i], 1e-12)), weights[i])
     )
+    n = len(order)
     cons = [tuple(sorted(c)) for c in constraints]
     item_cons: dict[int, list[int]] = {i: [] for i in items}
     for ci, c in enumerate(cons):
@@ -132,57 +161,54 @@ def branch_and_bound_mkp(
                 item_cons[i].append(ci)
     caps = [budget] * len(cons)
 
+    # Per-position views of the density order.
+    prof_at = [iprof[i] for i in order]
+    w_at = [weights[i] for i in order]
+    cons_at = [item_cons[i] for i in order]
+    # Suffix profit sums for a cheap generic bound.
+    suffix = [0] * (n + 1)
+    for k in range(n - 1, -1, -1):
+        suffix[k] = suffix[k + 1] + prof_at[k]
+    # Per constraint: its items' positions in ``order`` (ascending), their
+    # weights, and the suffix sums of their integer profits.
+    cpos: list[list[int]] = [[] for _ in cons]
+    for k in range(n):
+        for ci in cons_at[k]:
+            cpos[ci].append(k)
+    cw = [[w_at[k] for k in ps] for ps in cpos]
+    csuf = []
+    for ps in cpos:
+        s = [0] * (len(ps) + 1)
+        for j in range(len(ps) - 1, -1, -1):
+            s[j] = s[j + 1] + prof_at[ps[j]]
+        csuf.append(s)
+    # Fast comparison needs every integer below 2**52 (exact as a float).
+    fast_ok = suffix[0] < 2**52
+    tol_scale = (n + 2) * 2.0**-52
+
     best_set: list[int] = []
     best_val = 0
     expansions = 0
     exhausted = False
-
-    # Suffix profit sums for a cheap generic bound.
-    suffix = [0] * (len(order) + 1)
-    for k in range(len(order) - 1, -1, -1):
-        suffix[k] = suffix[k + 1] + iprof[order[k]]
-
-    def bound(k: int, cur: int, caps_now: list[float]) -> float:
-        """Upper bound for completing from item index k."""
-        generic = cur + suffix[k]
-        if not cons:
-            return generic
-        # Fractional knapsack on the tightest constraint only.
-        ci = min(range(len(cons)), key=lambda c: caps_now[c])
-        cap = caps_now[ci]
-        in_c = set(cons[ci])
-        ub = cur
-        frac_done = False
-        for idx in range(k, len(order)):
-            i = order[idx]
-            if i not in in_c:
-                ub += iprof[i]  # unconstrained under this relaxation
-            elif not frac_done:
-                w = weights[i]
-                if w <= cap:
-                    cap -= w
-                    ub += iprof[i]
-                else:
-                    if w > 0:
-                        ub += iprof[i] * (cap / w)
-                    frac_done = True  # constraint full; later in-c items add 0
-        return min(ub, generic)
+    tight = 0  # tightest constraint while caps is unchanged
+    caps_dirty = True
 
     # Explicit-stack DFS (include branch explored first, matching the
     # recursive formulation bitwise): partition-expanded graphs can have
-    # thousands of items, far past CPython's recursion limit. "undo" frames
-    # restore the capacity/chosen mutations when an include subtree is done.
+    # thousands of items, far past CPython's recursion limit. A frame is
+    # ``(k, cur)`` to visit position k, or ``(-1, k)`` to undo the include
+    # of position k (restore its capacity/chosen mutations).
     chosen: list[int] = []
-    stack: list[tuple] = [("visit", 0, 0)]
+    stack: list[tuple[int, int]] = [(0, 0)]
     while stack:
-        frame = stack.pop()
-        if frame[0] == "undo":
-            i = frame[1]
+        k, cur = stack.pop()
+        if k < 0:
             chosen.pop()
-            for ci in item_cons[i]:
-                caps[ci] += weights[i]
+            w = w_at[cur]
+            for ci in cons_at[cur]:
+                caps[ci] += w
+            caps_dirty = True
             continue
-        _, k, cur = frame
         expansions += 1
         if expansions > max_expansions:
             exhausted = True
@@ -190,21 +216,64 @@ def branch_and_bound_mkp(
         if cur > best_val:
             best_val = cur
             best_set = list(chosen)
-        if k >= len(order):
+        if k >= n:
             continue
-        if bound(k, cur, caps) <= best_val:
+        # Upper bound for completing from position k (see the docstring).
+        gen = cur + suffix[k]
+        if gen <= best_val:
             continue
-        i = order[k]
-        w = weights[i]
+        if cons:
+            if caps_dirty:
+                tight = caps.index(min(caps))
+                caps_dirty = False
+            cap = caps[tight]
+            ps = cpos[tight]
+            ws = cw[tight]
+            m = len(ps)
+            j = bisect_left(ps, k)
+            while j < m and ws[j] <= cap:
+                cap -= ws[j]
+                j += 1
+            if j < m:
+                # ps[j] is the first item of the constraint that does not fit
+                ub = gen - csuf[tight][j]
+                w = ws[j]
+                if w > 0:
+                    p = ps[j]
+                    f = prof_at[p] * (cap / w)
+                    y = (ub - best_val) + f
+                    if fast_ok and abs(y) > tol_scale * (ub + abs(f) + 1):
+                        prune = y < 0
+                    else:
+                        # replay the per-item walk's order of addition
+                        walk = (gen - suffix[p]) + f
+                        j += 1
+                        for idx in range(p + 1, n):
+                            if j < m and ps[j] == idx:
+                                j += 1
+                            else:
+                                walk += prof_at[idx]
+                        prune = walk <= best_val
+                    if prune:
+                        continue
+                elif ub <= best_val:
+                    continue
         # LIFO: push the exclude branch first so the include branch (and
         # its undo) run before it, exactly like the recursive include-first
-        stack.append(("visit", k + 1, cur))
-        if all(caps[ci] >= w - 1e-9 for ci in item_cons[i]):
-            for ci in item_cons[i]:
+        stack.append((k + 1, cur))
+        w = w_at[k]
+        lim = w - 1e-9
+        ks = cons_at[k]
+        for ci in ks:
+            if not caps[ci] >= lim:
+                break
+        else:
+            for ci in ks:
                 caps[ci] -= w
-            chosen.append(i)
-            stack.append(("undo", i))
-            stack.append(("visit", k + 1, cur + iprof[i]))
+            caps_dirty = True
+            chosen.append(order[k])
+            stack.append((-1, k))
+            stack.append((k + 1, cur + prof_at[k]))
     chosen = frozenset(best_set)
     return MKPResult(
         chosen=chosen,

@@ -3,7 +3,9 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+import repro.core.mkp as mkp
 from repro.core import (
+    CostModel,
     MVGraph,
     PAPER_COST_MODEL,
     score_graph,
@@ -11,6 +13,11 @@ from repro.core import (
     simplified_mkp,
     solve,
 )
+from repro.core.speedup import EFFECTIVE_NFS_COST_MODEL
+from repro.mv import UpdateSpec, paper_workloads
+from repro.mv.incremental import round_view
+from repro.mv.partition import partition_workload
+from test_mkp import reference_branch_and_bound_mkp
 
 
 def random_dag(draw, max_n=12):
@@ -98,3 +105,53 @@ def test_scores_from_cost_model_are_consistent(data):
             assert scored.scores[i] > 0.0
     plan = solve(scored, budget=sum(scored.sizes) / 2)
     assert scored.is_feasible(plan.flagged, plan.order, sum(scored.sizes) / 2)
+
+
+# ---------------------------------------------------------------------------
+# the benchmark cells' planner instances: same plans with the fast bound
+# ---------------------------------------------------------------------------
+
+# io3 on the modeled NFS tier, compute2 on local disk; P=8, k=4 workers, a
+# catalog of 1.6% of the dataset, 0.1% insert+delete refresh rounds.
+CELL_STORES = {"io3": EFFECTIVE_NFS_COST_MODEL, "compute2": CostModel()}
+
+
+def cell_instance(name, round_idx):
+    """The flat planner instance of a benchmark cell, built from the
+    workload's modeled sizes (no data): the P=8 expansion of
+    ``paper_workloads(100.0)``'s ``name``, scored for round ``round_idx``
+    as the scenario scores it (round 0 the build, later rounds the
+    refresh view)."""
+    cost_model = CELL_STORES[name]
+    wl = next(w for w in paper_workloads(100.0)
+              if w.name.split("@")[0] == name)
+    pwl, _ = partition_workload(wl, 8)
+    spec = UpdateSpec(mode="incremental", ingest_frac=0.001,
+                      update_frac=0.0, delete_frac=0.001, n_rounds=1)
+    view, _, _ = round_view(pwl, spec, cost_model, round_idx)
+    budget = 0.016 * sum(n.size for n in pwl.nodes)
+    return view.to_graph(cost_model), budget
+
+
+@pytest.mark.parametrize("round_idx", [0, 1], ids=["build", "refresh"])
+@pytest.mark.parametrize("name", sorted(CELL_STORES))
+def test_cell_plan_same_with_reference_bnb(monkeypatch, name, round_idx):
+    g, budget = cell_instance(name, round_idx)
+    capped = []
+
+    def solve_with(bnb):
+        def recorded(*args, **kwargs):
+            res = bnb(*args, **kwargs)
+            capped.append(not res.optimal)
+            return res
+
+        monkeypatch.setattr(mkp, "branch_and_bound_mkp", recorded)
+        return solve(g, budget, n_workers=4,
+                     node_kwargs={"max_expansions": 20_000})
+
+    got = solve_with(mkp.branch_and_bound_mkp)
+    want = solve_with(reference_branch_and_bound_mkp)
+    assert any(capped), "the instance no longer reaches the expansion cap"
+    assert got.order == want.order
+    assert got.flagged == want.flagged
+    assert got.score == want.score

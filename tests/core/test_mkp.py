@@ -1,5 +1,7 @@
 """MKP solver correctness: Algorithm 1 pieces + brute-force validation."""
 import itertools
+import random
+from typing import Sequence
 
 import pytest
 from hypothesis import given, settings
@@ -14,6 +16,7 @@ from repro.core import (
     ratio_select,
     simplified_mkp,
 )
+from repro.core.mkp import MKPResult
 
 
 # ---------------------------------------------------------------------------
@@ -157,3 +160,224 @@ def test_mkp_dominates_heuristics(data):
         uh = heur(g, budget, order)
         assert g.peak_memory(uh, order) <= budget + 1e-9
         assert g.total_score(u) >= g.total_score(uh) - 1e-9
+
+
+# ---------------------------------------------------------------------------
+# differential test: the bound's rewrite keeps the search bit for bit
+# ---------------------------------------------------------------------------
+
+# The branch-and-bound as it was before its bound walked per-constraint
+# positions and suffix sums (a linear scan over every remaining item and
+# every constraint per expansion), kept verbatim as the reference: the
+# rewrite must return the same MKPResult, expansion count included.
+def reference_branch_and_bound_mkp(
+    items: Sequence[int],
+    profits: dict[int, float],
+    weights: dict[int, float],
+    constraints: Sequence[frozenset[int]],
+    budget: float,
+    max_expansions: int = 200_000,
+) -> MKPResult:
+    """Maximize Σ profits[i]·x_i  s.t. for every constraint C:
+    Σ_{i∈C} weights[i]·x_i ≤ budget.
+
+    DFS over items sorted by profit density, with an upper bound from the
+    fractional relaxation of the single tightest constraint (dropping all
+    other constraints only increases the optimum, so the bound is valid).
+    """
+    # Integer-round profits (paper footnote 3) for the search; keep >=1 for
+    # any strictly positive score so rounding never erases a benefit.
+    iprof = {
+        i: max(1, round(profits[i])) if profits[i] > 0 else 0 for i in items
+    }
+    order = sorted(
+        items, key=lambda i: (-(iprof[i] / max(weights[i], 1e-12)), weights[i])
+    )
+    cons = [tuple(sorted(c)) for c in constraints]
+    item_cons: dict[int, list[int]] = {i: [] for i in items}
+    for ci, c in enumerate(cons):
+        for i in c:
+            if i in item_cons:
+                item_cons[i].append(ci)
+    caps = [budget] * len(cons)
+
+    best_set: list[int] = []
+    best_val = 0
+    expansions = 0
+    exhausted = False
+
+    # Suffix profit sums for a cheap generic bound.
+    suffix = [0] * (len(order) + 1)
+    for k in range(len(order) - 1, -1, -1):
+        suffix[k] = suffix[k + 1] + iprof[order[k]]
+
+    def bound(k: int, cur: int, caps_now: list[float]) -> float:
+        """Upper bound for completing from item index k."""
+        generic = cur + suffix[k]
+        if not cons:
+            return generic
+        # Fractional knapsack on the tightest constraint only.
+        ci = min(range(len(cons)), key=lambda c: caps_now[c])
+        cap = caps_now[ci]
+        in_c = set(cons[ci])
+        ub = cur
+        frac_done = False
+        for idx in range(k, len(order)):
+            i = order[idx]
+            if i not in in_c:
+                ub += iprof[i]  # unconstrained under this relaxation
+            elif not frac_done:
+                w = weights[i]
+                if w <= cap:
+                    cap -= w
+                    ub += iprof[i]
+                else:
+                    if w > 0:
+                        ub += iprof[i] * (cap / w)
+                    frac_done = True  # constraint full; later in-c items add 0
+        return min(ub, generic)
+
+    # Explicit-stack DFS (include branch explored first, matching the
+    # recursive formulation bitwise): partition-expanded graphs can have
+    # thousands of items, far past CPython's recursion limit. "undo" frames
+    # restore the capacity/chosen mutations when an include subtree is done.
+    chosen: list[int] = []
+    stack: list[tuple] = [("visit", 0, 0)]
+    while stack:
+        frame = stack.pop()
+        if frame[0] == "undo":
+            i = frame[1]
+            chosen.pop()
+            for ci in item_cons[i]:
+                caps[ci] += weights[i]
+            continue
+        _, k, cur = frame
+        expansions += 1
+        if expansions > max_expansions:
+            exhausted = True
+            break  # best_val/best_set already hold the incumbent
+        if cur > best_val:
+            best_val = cur
+            best_set = list(chosen)
+        if k >= len(order):
+            continue
+        if bound(k, cur, caps) <= best_val:
+            continue
+        i = order[k]
+        w = weights[i]
+        # LIFO: push the exclude branch first so the include branch (and
+        # its undo) run before it, exactly like the recursive include-first
+        stack.append(("visit", k + 1, cur))
+        if all(caps[ci] >= w - 1e-9 for ci in item_cons[i]):
+            for ci in item_cons[i]:
+                caps[ci] -= w
+            chosen.append(i)
+            stack.append(("undo", i))
+            stack.append(("visit", k + 1, cur + iprof[i]))
+    chosen = frozenset(best_set)
+    return MKPResult(
+        chosen=chosen,
+        objective=sum(profits[i] for i in chosen),
+        optimal=not exhausted,
+        expansions=expansions,
+    )
+
+
+
+
+# (seed, items, constraints, style, max_expansions)
+DIFF_CASES = [
+    (1, 10, 4, "int", 200_000),
+    (2, 12, 6, "int", 200_000),
+    (3, 14, 8, "float", 200_000),
+    (4, 12, 5, "float", 200_000),
+    (5, 16, 10, "ties", 200_000),
+    (6, 14, 7, "ties", 200_000),
+    (7, 14, 6, "density", 200_000),
+    (8, 12, 8, "density", 200_000),
+    (9, 14, 6, "heavy", 200_000),
+    (10, 12, 5, "heavy", 200_000),
+    (11, 13, 9, "zero", 200_000),
+    (12, 15, 7, "zero", 200_000),
+    (13, 40, 25, "int", 3_000),
+    (14, 40, 30, "float", 3_000),
+    (15, 36, 20, "ties", 2_000),
+    (16, 48, 36, "density", 4_000),
+    (17, 32, 16, "heavy", 2_500),
+    (18, 44, 28, "zero", 3_500),
+    (19, 60, 45, "float", 5_000),
+    (20, 24, 1, "int", 1_000),
+]
+
+
+def random_mkp(seed, n_items, n_cons, style):
+    """A seeded MKP instance. Every constraint overlaps others; caps all
+    start at the budget, so the tightest-constraint argmin ties at the root.
+
+    * ``int`` — small integer weights and profits under an integer budget,
+      so fractional terms often land exactly on an integer (the bound ties
+      the incumbent and the exact-replay path runs);
+    * ``float`` — real weights and profits, some rounding to 0 or 1;
+    * ``ties`` — repeated constraints and equal weights (argmin ties that
+      persist as capacities move);
+    * ``density`` — profit proportional to weight (equal densities);
+    * ``heavy`` — some weights above the budget;
+    * ``zero`` — many zero-profit items.
+    """
+    rng = random.Random(seed)
+    items = sorted(rng.sample(range(3 * n_items), n_items))
+    budget = 10 if style == "int" else 10.0
+    weights, profits = {}, {}
+    for i in items:
+        if style == "int":
+            weights[i] = rng.randint(1, 6)
+            profits[i] = rng.randint(0, 9)
+        elif style == "ties":
+            weights[i] = rng.choice((2.5, 5.0))
+            profits[i] = float(rng.choice((3, 6)))
+        elif style == "density":
+            weights[i] = rng.uniform(0.5, 6.0)
+            profits[i] = 2.0 * weights[i]
+        elif style == "heavy":
+            weights[i] = rng.uniform(1.0, 14.0)
+            profits[i] = rng.uniform(0.0, 20.0)
+        elif style == "zero":
+            weights[i] = rng.uniform(0.5, 6.0)
+            profits[i] = rng.uniform(0.0, 9.0) if rng.random() < 0.4 else 0.0
+        else:
+            weights[i] = rng.uniform(0.2, 7.0)
+            profits[i] = rng.uniform(0.0, 12.0)
+    constraints = []
+    for _ in range(n_cons):
+        size = rng.randint(2, max(2, n_items // 2))
+        constraints.append(frozenset(rng.sample(items, size)))
+    if style == "ties":
+        constraints += constraints[: max(1, n_cons // 2)]
+    return items, profits, weights, constraints, budget
+
+
+@pytest.mark.parametrize(
+    "seed,n_items,n_cons,style,max_exp", DIFF_CASES,
+    ids=[f"{c[3]}-{c[1]}x{c[2]}-s{c[0]}" for c in DIFF_CASES],
+)
+def test_bnb_matches_reference_search(seed, n_items, n_cons, style, max_exp):
+    items, profits, weights, constraints, budget = random_mkp(
+        seed, n_items, n_cons, style
+    )
+    args = (items, profits, weights, constraints, budget, max_exp)
+    got = branch_and_bound_mkp(*args)
+    want = reference_branch_and_bound_mkp(*args)
+    assert got.chosen == want.chosen
+    assert got.objective == want.objective
+    assert got.optimal == want.optimal
+    assert got.expansions == want.expansions
+
+
+def test_differential_cases_cover_exhausted_and_optimal_searches():
+    """The differential cases hold both kinds of search: some stop at their
+    expansion cap, the others prove optimality."""
+    optimal = {
+        branch_and_bound_mkp(*random_mkp(*c[:4]), c[4]).optimal
+        for c in DIFF_CASES
+    }
+    assert optimal == {True, False}
