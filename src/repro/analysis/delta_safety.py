@@ -15,6 +15,12 @@ Checks the invariants the incremental engine's correctness story rests on,
   _refresh_delta``); the pass surfaces where those fallbacks are *statically
   inevitable* (info-level: correct but worth knowing — the MV pays full
   recompute every round).
+* **Shared rids in a UNION** — two UNION inputs whose rid origins meet
+  (``workloads.rid_origins``: both descend from one scan along their
+  first inputs) can hold rows under one rid with different payloads; the
+  engine then regroups each touched shared rid every dirty round
+  (``tableops.zset_union_delta``, an old-input read per sharing input).
+  Info-level: correct, and where the ``union.splice`` cost comes from.
 * **AGG int64 fixed-point overflow** — sums accumulate as
   ``round(v * AGG_QUANTUM)`` in int64. Given a declared per-value scale and
   the modeled input row count, the worst-case |sum| is
@@ -33,6 +39,7 @@ import numpy as np
 
 from ..mv import ir as mvir
 from ..mv.tableops import AGG_QUANTUM
+from ..mv.workloads import rid_origins, union_shared_inputs
 from .findings import Finding
 
 __all__ = ["DELTA_RULES", "check_ir", "analyze_workload", "est_rows"]
@@ -46,7 +53,9 @@ DELTA_RULES: dict[str, str] = {
     "MAP": "weight-linear: derived column computed per row, weight kept",
     "JOIN": "bilinear: left weights pass through the PK probe; right-side "
             "mapping changes emit retract/insert corrections",
-    "UNION": "additive: weighted inputs concatenate and consolidate by rid",
+    "UNION": "additive: weighted inputs concatenate and consolidate by rid; "
+             "a rid that inputs of one scan origin share is regrouped: its "
+             "old copies retracted, its new ones inserted in input order",
     "AGG": "mergeable: signed partial aggregate folded by merge_agg",
 }
 
@@ -92,6 +101,7 @@ def check_ir(
         ingest = frozenset(ir.roots())
     out: list[Finding] = []
     dirty = _reaches(ir, ingest)
+    origins = rid_origins(ir.nodes)
 
     def add(rule, level, node, msg):
         out.append(Finding(rule, level, path, node.name, msg))
@@ -126,6 +136,14 @@ def check_ir(
             add("union-ridless-input", "info", node,
                 "a UNION input carries no rid: canonical rid order is "
                 "undefined, engine falls back to full recompute")
+        elif op == "UNION" and len(parents) >= 2 and node_dirty:
+            shared = union_shared_inputs(origins, node.parents)
+            if any(dirty[node.parents[i]] for i in shared):
+                add("union-shared-rids", "info", node,
+                    f"inputs {[parents[i].name for i in shared]} carry rids "
+                    "of one scan: rows under one rid can differ by input, "
+                    "so every dirty round regroups the shared rids it "
+                    "touches (old-input reads)")
         if retractions and node_dirty and op not in ("AGG", "SCAN") \
                 and not node.schema.has_rid:
             add("ridless-retraction", "info", node,

@@ -67,6 +67,8 @@ from .workloads import (
     Workload,
     adaptive_force_full,
     incremental_view,
+    rid_origins,
+    union_shared_inputs,
 )
 
 
@@ -132,6 +134,13 @@ class IncrementalEngine(ThreadedEngine):
         self.join_fallbacks = 0
         self.fb_affected = 0  # right-delta keys whose PK mapping changed
         self.fb_matched = 0   # ... that actually matched old-left rows
+        # UNION node -> positions of its inputs that can share a rid
+        origins = rid_origins(workload.nodes)
+        self._union_shared = {
+            v: shared for v, n in enumerate(workload.nodes)
+            if n.op == "UNION" and len(n.parents) >= 2
+            and (shared := union_shared_inputs(origins, n.parents))
+        }
 
     def configure_round(self, round_idx: int, static: Sequence[int] = (),
                         force_full: Sequence[int] = ()) -> None:
@@ -317,6 +326,10 @@ class IncrementalEngine(ThreadedEngine):
             # without the canonical rid order, so delta rows would land at
             # the wrong row positions — recompute fully instead
             self._refresh_full(v, rt)
+        elif v in self._union_shared and any(
+            self._rows(deltas[i]) for i in self._union_shared[v]
+        ):
+            self._refresh_union(v, deltas, retracting, rt)
         elif node.op == "AGG":
             # mergeable (signed) partial aggregates: agg the weighted delta,
             # merge exactly into the previous output (fixed-point sums —
@@ -331,13 +344,38 @@ class IncrementalEngine(ThreadedEngine):
             # AGG) has no row identity to splice against
             self._refresh_full(v, rt)
         else:
-            # FILTER / PROJECT / MAP / UNION: pure weighted pass-through;
+            # FILTER / PROJECT / MAP, and a UNION whose inputs share no
+            # rid: pure weighted pass-through;
             # the node's own compute fn applied to the delta IS the delta
             # rule (weights ride along as a meta column)
             deltas = [T.with_weight(d) for d in deltas] if retracting else deltas
             with obs_trace.span("compute", node.name):
                 out = node.fn(deltas)
             self._publish_delta(v, out, rt)
+
+    def _refresh_union(self, v: int, deltas: list[T.Table], retracting: bool,
+                       rt: _RunState) -> None:
+        """UNION whose inputs can hold rows under one rid (their rid origins
+        meet, ``workloads.rid_origins``) and changed this round: the
+        sharing inputs' old contents are read, and the rids they hold
+        twice are regrouped (``tableops.zset_union_delta``); every other
+        row passes through."""
+        node = self.workload.nodes[v]
+        shared = self._union_shared[v]
+        if retracting:
+            deltas = [T.with_weight(d) for d in deltas]
+        stats: dict = {}
+        with obs_trace.span("compute", node.name), \
+                obs_trace.span("union.splice", node.name) as sp:
+            olds = [self._old_content(p) if i in shared else None
+                    for i, p in enumerate(node.parents)]
+            out = T.zset_union_delta(olds, deltas, union=node.fn,
+                                     stats=stats)
+            sp.set(nbytes=stats["regroup_bytes"])
+        if stats["regroup_rows"] and obs_trace.enabled():
+            METRICS.inc("union_regroup_rows", stats["regroup_rows"],
+                        entry=node.name)
+        self._publish_delta(v, out, rt)
 
     def _full_from_delta(self, p: int, delta: T.Table) -> T.Table:
         """Parent ``p``'s full current content, assembled from its already-

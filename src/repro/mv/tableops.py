@@ -44,9 +44,12 @@ Per-operator delta rules:
   new keys, deleted keys, updated payloads — trigger a *partial fallback*
   that re-joins only the affected surviving old-left rows and splices the
   corrections by rid, instead of recomputing the whole node.
-* UNION sorts its output by ``rid`` (when both inputs carry one); the union
-  of Z-set deltas is the rid-consolidated concatenation of the input
-  deltas, spliced by ``apply_delta`` like any other weighted delta.
+* UNION sorts its output by ``rid`` (when both inputs carry one), stably,
+  so the copies of one rid stand in input order. Where no two inputs hold
+  a rid, the union of Z-set deltas is the rid-consolidated concatenation
+  of the input deltas. Inputs that descend from one scan can hold one rid
+  with different payloads; ``zset_union_delta`` then retracts each touched
+  shared rid's whole old group and inserts its new group in input order.
 * AGG keeps *mergeable partial aggregates*: per-key ``sum_*`` columns are
   accumulated in fixed-point int64 (quantum ``1/AGG_QUANTUM``) so addition
   is exactly associative, and ``count`` is an exact int64. Weighted rows
@@ -271,9 +274,10 @@ def apply_delta(old: Table, delta: Table) -> Table:
 
     Weights are general integers (duplicate-row sources): a ``+w`` row
     inserts ``w`` identical copies; a ``-w`` row retracts ``w`` copies of
-    its rid — stored copies under one rid are identical by construction, so
-    the first ``w`` occurrences (in rid order) are dropped, clamped to the
-    copies actually present.
+    its rid: the first ``w`` occurrences (in rid order) are dropped, clamped
+    to the copies actually present. Copies under one rid may differ only in
+    a UNION whose inputs share rids, and its delta retracts such a rid's
+    whole group (``zset_union_delta``), so which copies go never matters.
     """
     if not delta or n_rows(delta) == 0:
         return dict(old)
@@ -716,6 +720,85 @@ def op_union(left: Table, right: Table) -> Table:
     if WEIGHT_COL in out:
         out = consolidate_zset(out)
     return out
+
+
+def _rows_in(table: Table, rids: np.ndarray, inside: bool = True) -> Table:
+    """Rows of ``table`` whose rid is (``inside``) or is not in ``rids``."""
+    hit = np.isin(np.asarray(table["rid"]), rids)
+    return take_rows(table, np.nonzero(hit if inside else ~hit)[0])
+
+
+def _rid_counts(tables: list[Table], rids: np.ndarray) -> np.ndarray:
+    """Copies of each of the sorted unique ``rids`` held across ``tables``
+    (every row's rid is one of them)."""
+    counts = np.zeros(len(rids), np.int64)
+    for t in tables:
+        r = np.asarray(t["rid"])
+        if r.size:
+            counts += np.bincount(np.searchsorted(rids, r),
+                                  minlength=len(rids))
+    return counts
+
+
+def zset_union_delta(
+    olds: list[Table | None], deltas: list[Table], union,
+    stats: dict | None = None,
+) -> Table:
+    """Weighted delta of a UNION whose inputs may hold rows under one rid.
+
+    ``olds[i]`` is input ``i``'s old content, or ``None`` for an input that
+    shares no rid with another (its rows always pass through); ``deltas[i]``
+    its Z-set delta; ``union(tables)`` the node's operator (``op_union``
+    folded over the inputs). Copies of one rid can differ by input, so
+    ``apply_delta``'s "retract the first w copies" would drop the wrong
+    one, and a copy re-inserted from an earlier input would land after a
+    later input's. So a touched rid that the sharing inputs held and hold
+    two or more copies of (old count >= 1, old or new count >= 2) is
+    *regrouped*: the delta retracts each old copy with its exact payload
+    and inserts the new copies in input order — ``apply_delta`` then drops
+    the whole old group and its stable rid sort puts the new group where
+    ``union`` of the new inputs puts it. Every other rid passes through
+    as ``union(deltas)``, which is the whole result when nothing needs a
+    regroup. ``stats`` gets ``regroup_rows`` (retracted + inserted rows)
+    and ``regroup_bytes`` (their payload bytes)."""
+    share = [i for i, o in enumerate(olds) if o is not None]
+    rids = [np.asarray(deltas[i]["rid"]) for i in share]
+    touched = np.unique(np.concatenate(rids)) if rids else \
+        np.empty(0, np.int64)
+    regroup = touched[:0]
+    if touched.size:
+        old_t = {i: _rows_in(olds[i], touched) for i in share}
+        new_t = {i: apply_delta(old_t[i], deltas[i]) for i in share}
+        n_old = _rid_counts(list(old_t.values()), touched)
+        n_new = _rid_counts(list(new_t.values()), touched)
+        regroup = touched[(n_old >= 1) & (np.maximum(n_old, n_new) >= 2)]
+    if stats is not None:
+        stats.update(regroup_rows=0, regroup_bytes=0)
+    if not regroup.size:
+        return union(deltas)
+    ws = [with_weight(d) for d in deltas]
+    passed = union([_rows_in(w, regroup, inside=False) if i in old_t else w
+                    for i, w in enumerate(ws)])
+
+    def group(tables: dict[int, Table], weight: int) -> Table:
+        return union([
+            with_weight(_rows_in(tables[i], regroup), weight) if i in tables
+            else take_rows(w, np.empty(0, np.int64))
+            for i, w in enumerate(ws)
+        ])
+
+    retract, insert = group(old_t, -1), group(new_t, +1)
+    if stats is not None:
+        stats.update(
+            regroup_rows=n_rows(retract) + n_rows(insert),
+            regroup_bytes=table_nbytes(strip_weight(retract))
+            + table_nbytes(strip_weight(insert)),
+        )
+    out = {k: np.concatenate([np.asarray(t[k])
+                              for t in (passed, retract, insert)])
+           for k in passed}
+    order = np.argsort(out["rid"], kind="stable")
+    return {k: v[order] for k, v in out.items()}
 
 
 def empty_like(schema: dict[str, np.dtype]) -> Table:

@@ -57,6 +57,38 @@ def filter_threshold(i: int) -> float:
     have different selectivities)."""
     return -0.3 + 0.1 * (i % 7)
 
+
+def rid_origins(nodes: Sequence) -> list[frozenset[int]]:
+    """The scans whose rids each node's rows can carry (``nodes``: anything
+    with ``op`` and ``parents``, workload or IR nodes). Rids are unique
+    across scans (``tableops.make_rid_base``); every operator passes a
+    row's rid on from its first input, a UNION of two or more from each
+    input, and AGG drops it. So two UNION inputs can hold rows under one
+    rid only where their origins meet."""
+    out: list[frozenset[int]] = []
+    for i, n in enumerate(nodes):
+        if not n.parents:
+            out.append(frozenset({i}))
+        elif n.op == "AGG":
+            out.append(frozenset())
+        elif n.op == "UNION" and len(n.parents) >= 2:
+            out.append(frozenset().union(*(out[p] for p in n.parents)))
+        else:
+            out.append(out[n.parents[0]])
+    return out
+
+
+def union_shared_inputs(origins: Sequence[frozenset[int]],
+                        parents: Sequence[int]) -> tuple[int, ...]:
+    """Positions of the UNION inputs whose rid origins meet another
+    input's: the inputs whose rows can share a rid."""
+    return tuple(
+        i for i, p in enumerate(parents)
+        if any(origins[p] & origins[q]
+               for j, q in enumerate(parents) if j != i)
+    )
+
+
 # bytes/sec of pure compute per operator on the modeled engine
 OP_THROUGHPUT: dict[str, float] = {
     "SCAN": 3.0e9,

@@ -19,6 +19,8 @@ Standard series recorded by the instrumented stack:
 ``bytes_read`` / ``bytes_written``     DiskStore logical I/O per entry
 ``catalog_hits`` / ``catalog_misses``  engine gather outcomes per entry
 ``join_fallbacks``                     JOIN partial-fallback rounds
+``union_regroup_rows``                 rows a UNION's shared-rid regroup
+                                       retracted and inserted, per entry
 =====================================  =====================================
 """
 from __future__ import annotations

@@ -43,6 +43,10 @@ Span categories (the shared vocabulary; dotted suffixes refine a family):
                           (``hash``, ``pid``, ``map_mul``, ``encode``,
                           ``encode_w``, ``probe``): padding, the call and
                           the copy back; ``nbytes`` = bytes sent + returned
+``union.splice``          a UNION partition's delta where its inputs can
+                          share rids (``IncrementalEngine._refresh_union``):
+                          the sharing inputs' old reads and the regroup;
+                          ``nbytes`` = bytes of the rid groups rewritten
 ``jit.trace``             a data-plane kernel traced by JAX (instant, named
                           ``dp.<kernel>``): in steady state there are none
 ``redispatch``            a task moved off a lost/straggling host by the

@@ -146,3 +146,20 @@ def test_realized_default_workload_is_gating_clean(tmp_path):
     assert ir.n == len(wl.nodes)
     assert not gating(findings)
     assert all(f.path == f"ir:{wl.name}" for f in findings)
+
+
+def test_union_shared_rids_info_needs_inputs_of_one_scan():
+    ir = mvir.ViewIR((
+        node("a", "SCAN", schema=SCAN_S, size=1e4),
+        node("b", "SCAN", schema=SCAN_S, size=1e4),
+        node("f", "FILTER", parents=(0,), schema=SCAN_S, size=1e4),
+        node("m", "MAP", parents=(0,), schema=SCAN_S, size=1e4),
+        node("u", "UNION", parents=(2, 3, 1), schema=SCAN_S, size=1e4),
+        node("v", "UNION", parents=(2, 1), schema=SCAN_S, size=1e4),
+    ))
+    got = [f for f in check_ir(ir) if f.rule == "union-shared-rids"]
+    assert [(f.symbol, f.level) for f in got] == [("u", "info")]
+    assert "['f', 'm']" in got[0].message
+    # only b ingests: the inputs that share a rid never change
+    assert not [f for f in check_ir(ir, ingest=frozenset({1}))
+                if f.rule == "union-shared-rids"]

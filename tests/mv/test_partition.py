@@ -44,6 +44,9 @@ from repro.mv import (
 from repro.mv import tableops as T
 from repro.mv.engine import simulate_events
 from repro.mv.partition import canonical_order
+# imported here, not inside a test: the module defines @given tests, and
+# importing it while a hypothesis test runs nests them
+from tests.mv.test_tableops_delta import zset_delta
 
 CM = CostModel(
     disk_read_bw=50e6,
@@ -140,8 +143,6 @@ def test_zset_delta_routes_to_dirty_partitions_only(seed, P):
     partition); applying routed deltas per partition equals applying the
     whole delta, and partitions outside ``dirty_partitions`` receive no
     rows."""
-    from tests.mv.test_tableops_delta import zset_delta
-
     old = T.make_base_table(200, 4, seed=seed, key_mod=16,
                             rid_base=T.make_rid_base(0, 0))
     delta = zset_delta(old, seed + 5, n_ins=12, n_upd=10, n_del=8)
