@@ -1,7 +1,7 @@
 """External storage for materialized tables (paper: NFS via Hive/Parquet).
 
-``DiskStore`` persists tables (dicts of numpy arrays) as ``.npz`` files with
-atomic rename, an fsync'd manifest of completed materializations (the
+``DiskStore`` persists tables (dicts of numpy arrays) as flat column files
+with atomic rename, an fsync'd manifest of completed materializations (the
 restart/crash-recovery source of truth), and an optional bandwidth throttle so
 laptop-scale experiments can reproduce the paper's NFS read/write bandwidths
 (519.8 / 358.9 MB/s) or any slower tier. Throttling is keyed to the *logical*
@@ -28,11 +28,21 @@ commits atomically at the manifest update: a crash beforehand leaves the
 old entry (and its intact files) authoritative, with at most an orphan part
 file that readers ignore, the next write of that id overwrites, and
 ``delete`` sweeps.
+
+A part file is a magic, a little-endian u32 header length, a JSON header
+listing each column's name, ``dtype.str`` and shape in table order, and
+then each column's raw C-order bytes at a 64-byte-aligned offset. It is
+written with one ``write`` and read back with one ``readinto``, each column
+a writable, aligned ``np.frombuffer`` view of that one buffer. The format
+exists for the per-part decode cost, not for bandwidth: a container parsed
+member by member in Python (a zip's central directory, local headers,
+``.npy`` headers, CRCs) costs a part more than its read does, and more still
+while concurrent workers contend for the interpreter lock.
 """
 from __future__ import annotations
 
-import io
 import json
+import math
 import os
 import threading
 import time
@@ -45,6 +55,9 @@ from ..obs import trace as obs_trace
 from ..obs.metrics import METRICS
 
 Table = Mapping[str, np.ndarray]
+
+_MAGIC = b"SCCOLS1\n"
+_ALIGN = 64
 
 # Separator between an MV name and its partition id in the store namespace:
 # partition ``p`` of MV ``mv3`` lives under the entry name ``mv3@p2``. Each
@@ -93,6 +106,51 @@ def _tombstone_bytes_of(delta: Table) -> int:
     return int(round(total / n * n_tomb + payload / n * retract_mult))
 
 
+def _column_offsets(start: int, sizes: list[int]) -> tuple[list[int], int]:
+    """Offset of each column in a part file whose header ends at ``start``
+    (every column 64-byte aligned), and the file's length."""
+    offsets, end = [], start
+    for n in sizes:
+        end = -(-end // _ALIGN) * _ALIGN
+        offsets.append(end)
+        end += n
+    return offsets, end
+
+
+def _encode_part(table: Table) -> np.ndarray:
+    """The part file's bytes for ``table`` (module docstring)."""
+    cols = [(k, np.ascontiguousarray(v)) for k, v in table.items()]
+    meta = json.dumps([[k, a.dtype.str, a.shape] for k, a in cols]).encode()
+    head = _MAGIC + len(meta).to_bytes(4, "little") + meta
+    offsets, end = _column_offsets(len(head), [a.nbytes for _, a in cols])
+    out = np.zeros(end, np.uint8)
+    out[:len(head)] = np.frombuffer(head, np.uint8)
+    for off, (_, a) in zip(offsets, cols):
+        out[off:off + a.nbytes] = a.reshape(-1).view(np.uint8)
+    return out
+
+
+def _decode_part(buf: bytearray, path: Path) -> dict[str, np.ndarray]:
+    """Columns of a part file held in ``buf``, as views of it. Raises
+    ``ValueError`` on a foreign file or one whose length is not what its
+    header describes (a truncated file)."""
+    start = len(_MAGIC) + 4
+    if len(buf) < start or buf[:len(_MAGIC)] != _MAGIC:
+        raise ValueError(f"{path}: not a part file")
+    meta_len = int.from_bytes(buf[len(_MAGIC):start], "little")
+    cols = [(k, np.dtype(dt), tuple(shape))
+            for k, dt, shape in json.loads(buf[start:start + meta_len])]
+    offsets, end = _column_offsets(
+        start + meta_len, [math.prod(s) * dt.itemsize for _, dt, s in cols]
+    )
+    if end != len(buf):
+        raise ValueError(f"{path}: {len(buf)} bytes, its header needs {end}")
+    return {
+        k: np.frombuffer(buf, dt, math.prod(s), off).reshape(s)
+        for off, (k, dt, s) in zip(offsets, cols)
+    }
+
+
 class DiskStore:
     def __init__(
         self,
@@ -118,8 +176,8 @@ class DiskStore:
     # -- paths ----------------------------------------------------------------
     def _path(self, name: str, part_id: int = 0) -> Path:
         if part_id == 0:
-            return self.root / f"{name}.npz"
-        return self.root / f"{name}.part{part_id}.npz"
+            return self.root / f"{name}.col"
+        return self.root / f"{name}.part{part_id}.col"
 
     def exists(self, name: str) -> bool:
         return name in self._entries()
@@ -212,9 +270,7 @@ class DiskStore:
         nbytes = table_nbytes(table)
         with obs_trace.span("io.write", name, nbytes):
             t0 = time.perf_counter()
-            buf = io.BytesIO()
-            np.savez(buf, **{k: np.asarray(v) for k, v in table.items()})
-            data = buf.getvalue()
+            data = _encode_part(table)
             target = self._path(name, part)
             # writer-unique tmp name: under multi-host speculation two
             # workers may durably write the *same* part id concurrently
@@ -222,7 +278,7 @@ class DiskStore:
             # needs its own staging file so one rename cannot strand the
             # other's, and whichever os.replace lands last wins harmlessly
             tmp = target.with_suffix(
-                f".npz.tmp{os.getpid()}-{threading.get_ident()}"
+                f".col.tmp{os.getpid()}-{threading.get_ident()}"
             )
             with open(tmp, "wb") as f:
                 f.write(data)
@@ -332,8 +388,12 @@ class DiskStore:
         return self.write(name, self.read(name))
 
     def _load_part(self, name: str, part_id: int) -> dict[str, np.ndarray]:
-        with np.load(self._path(name, part_id)) as z:
-            return {k: z[k] for k in z.files}
+        path = self._path(name, part_id)
+        with open(path, "rb") as f:
+            buf = bytearray(os.fstat(f.fileno()).st_size)
+            if f.readinto(buf) != len(buf):
+                raise ValueError(f"{path}: short read")
+        return _decode_part(buf, path)
 
     def _throttle_read(self, t0: float, nbytes: int, name: str = "") -> None:
         if self.read_bw:
@@ -443,8 +503,8 @@ class DiskStore:
                 self._write_manifest(m)
         # sweep every part file — manifest-referenced, orphaned by a crashed
         # rewrite, or a stale .tmp left mid-write
-        for path in (self.root.glob(f"{name}.npz*"),
-                     self.root.glob(f"{name}.part*.npz*")):
+        for path in (self.root.glob(f"{name}.col*"),
+                     self.root.glob(f"{name}.part*.col*")):
             for p in path:
                 p.unlink(missing_ok=True)
 

@@ -57,11 +57,61 @@ def test_diskstore_throttle_and_counters(tmp_path):
     assert store.read_seconds >= 0.09
 
 
+def _rows_table(n):
+    rng = np.random.default_rng(n)
+    vals = rng.standard_normal((3, n)).astype(np.float32)
+    if n >= 4:  # bit patterns a value-level comparison would not tell apart
+        vals[0, :4] = [-0.0, np.nan, np.float32(1e-45), np.inf]
+    return {"key": rng.integers(0, 1 << 40, n, dtype=np.int64),
+            "rid": np.arange(n, dtype=np.int64),
+            "c0": vals[0], "c1": vals[1], "c2": vals[2]}
+
+
+_PART_TABLES = {
+    "rows": _rows_table(3550),
+    "zset_delta": {
+        "rid": np.array([7, 3, 7, 11], np.int64),
+        "weight": np.array([-1, -1, 1, 1], np.int64),
+        "key": np.array([5, 2, 5, 9], np.int64),
+        "c0": np.array([1.5, -0.0, np.nan, 4.25], np.float32),
+    },
+    "agg": {
+        "key": np.array([2, 5, 9], np.int64),
+        "sum_c0": np.array([-(1 << 62), 65536, 3], np.int64),
+        "count": np.array([4, 1, 2], np.int64),
+    },
+    "empty": _rows_table(0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PART_TABLES))
+def test_part_file_roundtrip_is_bitwise_and_truncation_raises(tmp_path, case):
+    """A part reads back bit for bit, in column order, as writable aligned
+    arrays; a part file cut short, or not a part file, raises instead of
+    reading wrong."""
+    t = _PART_TABLES[case]
+    store = DiskStore(tmp_path)
+    store.write("mv", {"rid": np.zeros(1, np.int64)})
+    store.append("mv", t)
+    back = store.read_parts("mv", 1)  # the raw part, weights intact
+    assert list(back) == list(t)
+    for k, v in t.items():
+        assert back[k].dtype == v.dtype and back[k].shape == v.shape
+        assert back[k].tobytes() == v.tobytes()
+        assert back[k].flags.writeable and back[k].flags.aligned
+    path = store._path("mv", store._part_ids("mv")[-1])
+    whole = path.read_bytes()
+    for damaged in (whole[:-1], b"PK\x03\x04" + whole[4:]):
+        path.write_bytes(damaged)
+        with pytest.raises(ValueError):
+            store.read_parts("mv", 1)
+
+
 def test_diskstore_write_is_atomic(tmp_path):
     store = DiskStore(tmp_path)
     store.write("a", {"x": np.arange(4)})
     # a stray tmp file (simulated crash) must not appear in the manifest
-    (tmp_path / "b.npz.tmp").write_bytes(b"partial")
+    (tmp_path / "b.col.tmp").write_bytes(b"partial")
     assert not store.exists("b")
 
 
@@ -277,7 +327,7 @@ def test_diskstore_tombstone_crash_atomicity_and_stale_tmp_sweep(tmp_path):
     # a stale .tmp survives, but the process dies before _record
     new_id = max(store._part_ids("mv")) + 1
     store._write_part("mv", new_id, expect)
-    (tmp_path / "mv.part99.npz.tmp").write_bytes(b"partial")
+    (tmp_path / "mv.part99.col.tmp").write_bytes(b"partial")
     fresh = DiskStore(tmp_path)  # reader after restart
     got = fresh.read("mv")
     for k in expect:
@@ -322,7 +372,7 @@ def test_diskstore_delete_removes_parts_and_tmp(tmp_path):
     t = {"x": np.arange(8)}
     store.write("mv", t)
     store.append("mv", t)
-    (tmp_path / "mv.npz.tmp").write_bytes(b"partial")  # crashed rewrite
+    (tmp_path / "mv.col.tmp").write_bytes(b"partial")  # crashed rewrite
     store.delete("mv")
     assert not store.exists("mv")
-    assert list(tmp_path.glob("mv*.npz*")) == []
+    assert list(tmp_path.glob("mv*.col*")) == []
