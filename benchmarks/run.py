@@ -2,11 +2,10 @@
 
 Runs every paper-figure benchmark (Figs. 9–14, Tables IV–V), the
 full-vs-incremental update comparison, the real-executor wall-clock
-validation, the operator-throughput microbenchmark, and the roofline report
-from whatever dry-run records exist. ``--quick`` trims sweep sizes;
-``--smoke`` runs only the fast scenario-regression subset (the incremental
-benchmark in quick mode, plus the data-plane parity gate) for CI. Exit code
-is non-zero if any module raises.
+validation, and the operator-throughput microbenchmark. ``--quick`` trims
+sweep sizes; ``--smoke`` runs only the fast scenario-regression subset (the
+incremental benchmark in quick mode, plus the data-plane parity gate) for
+CI. Exit code is non-zero if any module raises.
 
 Host-parallel JAX data plane
 ----------------------------
@@ -45,7 +44,6 @@ def _modules():
         partition_sweep,
         planner_scale,
         real_executor,
-        roofline,
         table4_readtime,
         table5_cluster,
         tableops_bench,
@@ -67,7 +65,6 @@ def _modules():
         ("fig14_sweep", fig14_sweep.run),
         ("real_executor", real_executor.run),
         ("tableops_bench", tableops_bench.run),
-        ("roofline", lambda quick: roofline.run(mesh="single", quick=quick)),
     ]
 
 

@@ -10,7 +10,7 @@ lightweight so ``core.altopt`` can reuse ``plan_check`` without cycles):
 * ``determinism``   — AST lints (unstable sorts, value-like static jit
   arguments, x64-state leaks) and jaxpr lints (transcendentals / FMA
   contraction / silent f32 downcasts inside bitwise-contract kernels) for
-  ``mv/dataplane.py`` and ``kernels/``.
+  ``mv/dataplane.py``.
 * ``plan_check``    — plan feasibility as a reusable analyzer: minimal
   counterexample interleavings and the shed-repair loop the hierarchical
   planner uses.

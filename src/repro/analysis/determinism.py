@@ -1,8 +1,8 @@
-"""Determinism linting of the data plane and kernels (sc-lint pass family 2).
+"""Determinism linting of the data plane (sc-lint pass family 2).
 
 Two layers, both encoding hazards this repo actually shipped and fixed:
 
-**Source (AST) lints** over ``mv/`` and ``kernels/``:
+**Source (AST) lints** over ``mv/``:
 
 * ``unstable-sort`` — ``argsort`` without ``kind="stable"``. An unstable
   grouping sort feeding an order-sensitive consumer breaks bitwise
@@ -57,11 +57,10 @@ __all__ = [
 # flow. Anything else marked static is treated as value-like.
 SIZE_LIKE_STATIC_ARGS = frozenset({
     "P", "n", "L", "steps", "chunk", "chunks", "axis", "ndim", "width",
-    "depth", "block", "block_q", "block_k", "bq", "bk", "interpret",
-    "causal", "heads", "dim", "n_partitions",
+    "depth", "block", "interpret", "n_partitions",
 })
 
-DEFAULT_LINT_GLOBS = ("src/repro/mv/*.py", "src/repro/kernels/*.py")
+DEFAULT_LINT_GLOBS = ("src/repro/mv/*.py",)
 
 STABLE_KINDS = ("stable", "mergesort")
 
@@ -335,8 +334,7 @@ def lint_jaxpr(
 
 def lint_dataplane_kernels() -> list[Finding]:
     """Trace every jitted XLA kernel of ``mv.dataplane`` with representative
-    arguments and lint the jaxprs. Model kernels (``kernels/ops.py``) are
-    out of scope: they carry no bitwise contract."""
+    arguments and lint the jaxprs."""
     import numpy as np
 
     try:

@@ -1,11 +1,10 @@
 """Dependency-graph model for S/C Opt (paper §IV).
 
 An ``MVGraph`` is a DAG whose nodes are individual materialization jobs (MV
-updates in the paper; dataset/checkpoint/activation artifacts in the framework
-integrations). Each node carries a size ``s_i`` (bytes the artifact occupies in
-the Memory Catalog) and a speedup score ``t_i`` (estimated end-to-end seconds
-saved by *flagging* the node, i.e. keeping its output in bounded memory until
-its last consumer has executed).
+updates in the paper). Each node carries a size ``s_i`` (bytes the artifact
+occupies in the Memory Catalog) and a speedup score ``t_i`` (estimated
+end-to-end seconds saved by *flagging* the node, i.e. keeping its output in
+bounded memory until its last consumer has executed).
 
 Core semantics implemented here, exactly as defined in the paper:
 

@@ -2,8 +2,8 @@
 
 Once S/C short-circuits storage I/O, per-round wall time is dominated by the
 CPU operator inner loops in ``tableops.py``/``partition.py``. This module
-ports those loops to jitted JAX with a Pallas path, behind the same
-``impl=`` dispatch idiom as ``kernels/ops.py``:
+ports those loops to jitted JAX with a Pallas path, behind one ``impl=``
+dispatch:
 
 * ``numpy``     — the bitwise REFERENCE: exactly the vectorized host code
                   the operators always ran, and the data plane off a TPU.
@@ -27,9 +27,7 @@ ports those loops to jitted JAX with a Pallas path, behind the same
 
 Resolution order: explicit ``impl=`` argument > ``SC_DATAPLANE`` env (read
 ONCE at import; override at runtime with ``set_impl``/``use_impl``) > the
-shared ``kernels.dispatch`` configured impl (``REPRO_KERNEL_IMPL``, so the
-two dispatch layers agree) > the platform: ``xla`` when JAX's default
-backend is a TPU, ``numpy`` otherwise.
+platform: ``xla`` when JAX's default backend is a TPU, ``numpy`` otherwise.
 
 Host-only on every platform and for every impl — where XLA on a TPU v5e
 does not reproduce numpy bit for bit, or compiles too slowly to use
@@ -78,7 +76,7 @@ Non-numpy impls require JAX x64 (int64/uint64 table columns); it is
 enabled lazily, per jitted call, through the exception-safe ``_lazy_x64``
 scope: on success the setting stays enabled (lazy), but a kernel that raises
 restores the prior state — an ``SC_DATAPLANE`` impl switch whose first call
-fails cannot leak x64 into the f32-default model stack. ``use_impl``
+fails cannot leak x64 into other JAX code in the process. ``use_impl``
 restores both the impl and the prior x64 setting on exit.
 
 Tracing (``obs.trace``): on the ``xla`` path each device call is one
@@ -95,7 +93,6 @@ from functools import lru_cache
 
 import numpy as np
 
-from ..kernels import dispatch as _dispatch
 from ..obs import trace as obs_trace
 
 __all__ = [
@@ -148,8 +145,8 @@ _configured: str = _read_env()
 
 
 def configured_impl() -> str:
-    """The configured data-plane impl ("auto" defers to kernels.dispatch,
-    then the platform). Environment is read once at import."""
+    """The configured data-plane impl ("auto" defers to the platform).
+    Environment is read once at import."""
     return _configured
 
 
@@ -179,11 +176,6 @@ def resolve_impl(impl: str = "auto") -> str:
         return impl
     if _configured != "auto":
         return _configured
-    # defer to the shared kernel dispatch so REPRO_KERNEL_IMPL moves both
-    # layers; its own "auto" means "nothing configured"
-    shared = _dispatch.kernel_impl()
-    if shared != "auto":
-        return shared
     return "xla" if platform() == "tpu" else "numpy"
 
 
@@ -191,7 +183,7 @@ def resolve_impl(impl: str = "auto") -> str:
 def use_impl(impl: str):
     """Scoped impl override: sets the configured impl and restores both the
     impl and the prior JAX x64 setting on exit (normal or exceptional) — so
-    a jax-path test leaves the f32-default model tests alone."""
+    a jax-path test leaves the process's x64 setting as it found it."""
     import jax
 
     prev_x64 = bool(jax.config.jax_enable_x64)
@@ -212,7 +204,7 @@ def _lazy_x64():
     on success (lazy: later calls pay nothing) — but if the kernel raises,
     the prior setting is restored before the error propagates, so switching
     ``SC_DATAPLANE`` to a broken impl cannot leak x64 state into unrelated
-    f32 model code.
+    f32 JAX code.
     """
     import jax
 

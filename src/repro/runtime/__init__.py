@@ -1,4 +1,4 @@
-"""Runtime fault tolerance: preemption, stragglers, elastic restarts."""
-from .ft import PreemptionHandler, StragglerDetector, elastic_restore
+"""Runtime fault tolerance: preemption and stragglers."""
+from .ft import PreemptionHandler, StragglerDetector
 
-__all__ = ["PreemptionHandler", "StragglerDetector", "elastic_restore"]
+__all__ = ["PreemptionHandler", "StragglerDetector"]

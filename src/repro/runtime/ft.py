@@ -1,18 +1,14 @@
-"""Fault tolerance: preemption-safe shutdown, straggler detection, elastic
-restart.
+"""Fault tolerance for the multi-host refresh (``mv.multihost``):
+preemption-safe shutdown and straggler detection.
 
-Designed for 1000+-node operation: every mechanism is per-host-local with
-O(1) state, no global coordination beyond what the checkpoint already
-provides.
+Every mechanism is per-host-local with O(1) state and no global
+coordination.
 
-* ``PreemptionHandler`` — converts SIGTERM/SIGINT into a cooperative flag the
-  training loop polls; the loop checkpoints (write-behind flushed) and exits 0
-  so the scheduler restarts cleanly from LATEST.
+* ``PreemptionHandler`` — converts SIGTERM/SIGINT into a cooperative flag a
+  worker polls, so it can drain and exit cleanly.
 * ``StragglerDetector`` — per-host step-duration EWMA vs the fleet median;
   hosts slower than ``threshold ×`` median for ``patience`` consecutive steps
   are flagged (driver action: re-dispatch/evict — here surfaced as events).
-* ``elastic_restore`` — checkpoints are topology-agnostic numpy; restoring on
-  a different mesh is just device_put with the new shardings.
 """
 from __future__ import annotations
 
@@ -20,7 +16,7 @@ import dataclasses
 import signal
 import statistics
 import threading
-from typing import Any, Callable
+from typing import Any
 
 
 class PreemptionHandler:
@@ -92,30 +88,3 @@ class StragglerDetector:
                 self._strikes[h] = 0  # re-arm after reporting
         return flagged
 
-
-def elastic_restore(flat: dict, template: Any, shardings: Any = None) -> Any:
-    """Rebuild a state pytree from a topology-agnostic checkpoint dict on the
-    *current* mesh (which may differ from the one that saved it).
-
-    jax is imported here, not at module top: ``PreemptionHandler`` and
-    ``StragglerDetector`` are wired into the multi-host MV refresh path
-    (``mv.multihost``), whose forked worker processes must not inherit an
-    initialized accelerator runtime just to poll a signal flag."""
-    import jax
-
-    paths = jax.tree_util.tree_flatten_with_path(template)
-    out = []
-    shard_leaves = (
-        jax.tree_util.tree_leaves(
-            shardings, is_leaf=lambda x: hasattr(x, "spec")
-        )
-        if shardings is not None
-        else [None] * len(paths[0])
-    )
-    for (path, leaf), sh in zip(paths[0], shard_leaves):
-        key = "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
-        arr = jax.numpy.asarray(flat[key]).astype(leaf.dtype)
-        if sh is not None:
-            arr = jax.device_put(arr, sh)
-        out.append(arr)
-    return jax.tree_util.tree_unflatten(paths[1], out)

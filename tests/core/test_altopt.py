@@ -1,4 +1,6 @@
 """Algorithm 2 (alternating optimization): convergence, feasibility, quality."""
+import functools
+
 import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
@@ -14,7 +16,7 @@ from repro.core import (
     solve,
 )
 from repro.core.speedup import EFFECTIVE_NFS_COST_MODEL
-from repro.mv import UpdateSpec, paper_workloads
+from repro.mv import PAPER_WORKLOAD_SPECS, UpdateSpec, paper_workloads
 from repro.mv.incremental import round_view
 from repro.mv.partition import partition_workload
 from test_mkp import reference_branch_and_bound_mkp
@@ -67,13 +69,36 @@ def test_zero_budget_flags_nothing_expensive():
     assert all(g.sizes[i] == 0 for i in plan.flagged)
 
 
-def test_all_node_and_order_solvers_run():
-    g = fig7_style_reordered()
-    for ns in ("mkp", "greedy", "random", "ratio"):
-        for os_ in ("madfs", "random_dfs", "sa", "separator"):
-            plan = solve(g, budget=100.0, node_solver=ns, order_solver=os_)
-            assert g.is_feasible(plan.flagged, plan.order, 100.0)
+# the solver-pairing graphs: fig7 at budget 100, and each Table III graph at
+# 1.6% of its bytes (the paper's Memory Catalog share, the cells' budget)
+PAIRING_GRAPHS = ("fig7", *(name for name, *_ in PAPER_WORKLOAD_SPECS))
+
+
+@functools.lru_cache(maxsize=None)
+def pairing_graph(name):
+    """(graph, budget) for a solver-pairing case, built once per module."""
+    if name == "fig7":
+        return fig7_style_reordered(), 100.0
+    wi = [n for n, *_ in PAPER_WORKLOAD_SPECS].index(name)
+    g = paper_workloads(100.0)[wi].to_graph(CostModel())
+    return g, 0.016 * sum(g.sizes)
+
+
+@pytest.mark.parametrize("os_", ["madfs", "random_dfs", "sa", "separator"])
+@pytest.mark.parametrize("ns", ["mkp", "greedy", "random", "ratio"])
+@pytest.mark.parametrize("graph", PAIRING_GRAPHS)
+def test_all_node_and_order_solvers_run(graph, ns, os_):
+    g, budget = pairing_graph(graph)
+    plan = solve(g, budget=budget, node_solver=ns, order_solver=os_)
+    assert g.is_feasible(plan.flagged, plan.order, budget)
+    assert g.is_topological(list(plan.order))
+    again = solve(g, budget=budget, node_solver=ns, order_solver=os_)
+    assert again.flagged == plan.flagged and again.order == plan.order
+
+
+def test_mkp_madfs_scores_at_least_each_baseline():
     # MKP+MA-DFS is the paper's choice and must be at least as good here
+    g = fig7_style_reordered()
     best = solve(g, budget=100.0).score
     for ns in ("greedy", "random", "ratio"):
         assert best >= solve(g, budget=100.0, node_solver=ns).score - 1e-9

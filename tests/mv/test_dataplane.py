@@ -10,8 +10,8 @@ End-to-end: the full partitioned scenario matrix under ``SC_DATAPLANE=jax``
 is bitwise-identical to the numpy-path full recompute.
 
 Dispatch contract: env read once at import, runtime overrides through
-``set_impl``/``use_impl`` (which restores the JAX x64 setting), and the
-shared ``kernels.dispatch`` resolver keeps both dispatch layers agreeing.
+``set_impl``/``use_impl`` (which restores the JAX x64 setting), and with
+nothing configured the platform decides.
 """
 import shutil
 import tempfile
@@ -20,7 +20,6 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from repro.kernels import dispatch
 from repro.mv import dataplane as dp
 from repro.mv import tableops as T
 from repro.mv.partition import dirty_partitions, partition_of, partition_table
@@ -250,28 +249,22 @@ def test_env_read_once_and_override_hook(monkeypatch):
     # mutating the environment mid-run must NOT flip the resolved impl...
     monkeypatch.setenv("SC_DATAPLANE", "jax")
     assert dp.resolve_impl("auto") == "numpy"  # config captured at import
-    monkeypatch.setenv("REPRO_KERNEL_IMPL", "interpret")
-    assert dispatch.resolve("auto") != "interpret"
-    # ...the explicit hooks do
+    # ...the explicit hook does
     prev = dp.set_impl("jax")
     try:
         assert dp.resolve_impl("auto") == "xla"
+        # ``None`` re-reads the environment
+        assert dp.set_impl(None) == "xla"
+        assert dp.resolve_impl("auto") == "xla"
     finally:
         dp.set_impl(prev)
-    prevk = dispatch.set_kernel_impl("interpret")
-    try:
-        assert dispatch.resolve("auto") == "interpret"
-        # the shared resolver moves the data plane too (layers agree)
-        assert dp.resolve_impl("auto") == "interpret"
-    finally:
-        dispatch.set_kernel_impl(prevk)
+    assert dp.resolve_impl("auto") == "numpy"
 
 
 def test_auto_resolves_from_the_platform(monkeypatch):
     """With nothing configured: numpy reference on CPU, the jitted xla
     path on a TPU."""
     monkeypatch.setattr(dp, "_configured", "auto")
-    monkeypatch.setattr(dispatch, "_configured", "auto")
     assert dp.platform() == "cpu"
     assert dp.resolve_impl("auto") == "numpy"
     monkeypatch.setattr(dp, "platform", lambda: "tpu")
@@ -300,7 +293,7 @@ def test_impl_aliases_and_validation():
     with pytest.raises(ValueError):
         dp.set_impl("cuda")
     with pytest.raises(ValueError):
-        dispatch.set_kernel_impl("not-an-impl")
+        dp.resolve_impl("not-an-impl")
 
 
 # ---------------------------------------------------------------------------

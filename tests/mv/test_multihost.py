@@ -17,6 +17,8 @@ suite, per-host budget accounting, and the bitwise acceptance matrix.
   next round and receives work.
 """
 import tempfile
+import threading
+import time
 
 import pytest
 
@@ -37,6 +39,7 @@ from repro.mv import (
     run_partitioned_scenario,
     verify_scenario_equivalence,
 )
+from repro.mv import multihost
 from repro.mv.partition import expand_update_spec
 from repro.obs import trace as obs_trace
 
@@ -201,12 +204,36 @@ def test_straggler_delay_redispatches_and_stays_bitwise():
     assert_no_catalog_leak(rep)
 
 
-def test_flagged_then_recovered_host_gets_work_again():
+def test_flagged_then_recovered_host_gets_work_again(monkeypatch):
     """Straggler suspicion is per round: a host flagged in round 1 (delay
-    cleared at the round boundary) executes work again in round 2."""
+    cleared at the round boundary) executes work again in round 2. The
+    delayed host is held until the detector flags it (at most 30 s), not
+    for a fixed time, so the trip does not depend on the machine's load."""
+    flagged = threading.Event()
+
+    class WatchedDetector(multihost.StragglerDetector):
+        def observe(self, step, durations):
+            hosts = super().observe(step, durations)
+            if 2 in hosts:
+                flagged.set()
+            return hosts
+
+    class HeldTime:
+        """``time`` as the multi-host module sees it, except that a sleep
+        (in that module only the delay fault sleeps) ends once host 2 is
+        flagged."""
+
+        def __getattr__(self, name):
+            return getattr(time, name)
+
+        def sleep(self, seconds):
+            flagged.wait(seconds)
+
+    monkeypatch.setattr(multihost, "StragglerDetector", WatchedDetector)
+    monkeypatch.setattr(multihost, "time", HeldTime())
     fp = FaultPlan(
         (FaultAction("delay", host=2, round_idx=1, after_tasks=0,
-                     seconds=0.4),)
+                     seconds=30.0),)
     )
     rep, store = run_mh(
         7, "insert", 4, backend="thread", fault_plan=fp,

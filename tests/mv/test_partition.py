@@ -4,7 +4,8 @@ planning/storage/catalog, dirty-partition pruning, and the acceptance matrix.
 * partitioned == unpartitioned, bitwise: every operator run per partition
   and reassembled in canonical rid order equals unpartitioned execution,
   over random operator chains and over full multi-round refresh scenarios
-  (3 seeds x P in {1,2,8} x k in {1,4} x update kinds);
+  ((3 generated DAGs + the five Table III workloads) x update kinds x
+  P in {1,2,8} x k in {1,4});
 * Z-set deltas route to exactly the partitions their keys hash to, so
   UPDATE/DELETE rounds touch only dirty partitions (clean ones are pruned);
 * the planner scores fractional residency: P=1 degenerates to the whole-MV
@@ -13,6 +14,7 @@ planning/storage/catalog, dirty-partition pruning, and the acceptance matrix.
 * per-partition part-file groups commit atomically at the manifest, and the
   Memory Catalog admits/releases partitions independently.
 """
+import functools
 import shutil
 import tempfile
 from pathlib import Path
@@ -25,12 +27,14 @@ from hypothesis import strategies as st
 from repro.core import CostModel, serial_plan, solve, solve_partitioned
 from repro.core.speedup import partition_shares
 from repro.mv import (
+    PAPER_WORKLOAD_SPECS,
     DiskStore,
     MemoryCatalog,
     UpdateSpec,
     concat_partitions,
     dirty_partitions,
     generate_workload,
+    paper_workloads,
     partition_entry_name,
     partition_of,
     partition_table,
@@ -354,37 +358,76 @@ KINDS = {
 }
 
 
+# the scenario matrix's workloads: three generated 8-MV DAGs (by seed) and
+# the five Table III workloads, each realized at 4 KiB per root
+SCENARIO_WORKLOADS = (
+    "seed3", "seed11", "seed2026",
+    *(name for name, *_ in PAPER_WORKLOAD_SPECS),
+)
+
+
+@functools.lru_cache(maxsize=None)
+def scenario_workload(name):
+    """The realized workload ``name`` and its catalog budget (40% of its
+    bytes), built once per module."""
+    if name.startswith("seed"):
+        wl = generate_workload(8, seed=int(name[len("seed"):]))
+        wl = realize_workload(wl, bytes_per_root=1 << 12)
+    else:
+        wi = [n for n, *_ in PAPER_WORKLOAD_SPECS].index(name)
+        wl = realize_workload(paper_workloads(100.0)[wi],
+                              bytes_per_root=1 << 12, seed=wi)
+    return wl, sum(n.size for n in wl.nodes) * 0.4
+
+
+@pytest.fixture(scope="module")
+def full_recompute():
+    """The unpartitioned ``mode="full"`` reference store of a (workload,
+    kind), run once per module and shared by every P x k case."""
+    root = Path(tempfile.mkdtemp(prefix="part_ref_"))
+    refs = {}
+
+    def get(name, kind):
+        if (name, kind) not in refs:
+            wl, budget = scenario_workload(name)
+            ref = DiskStore(root / f"{name}_{kind}")
+            run_scenario(wl, ref, budget,
+                         UpdateSpec(mode="full", **KINDS[kind]), CM)
+            refs[name, kind] = ref
+        return refs[name, kind]
+
+    yield get
+    shutil.rmtree(root, ignore_errors=True)
+
+
 # acceptance: partitioned refresh output is bitwise-identical to the
-# unpartitioned full recompute across 3 seeds x P in {1,2,8} x k in {1,4}
-# x update kinds (insert-only and mixed insert/update/delete)
-@pytest.mark.parametrize("seed", [3, 11, 2026])
-def test_scenario_matrix_partitioned_bitwise_vs_full_recompute(seed):
-    tmp_path = Path(tempfile.mkdtemp(prefix=f"part{seed}_"))
+# unpartitioned full recompute across workloads x update kinds (insert-only
+# and mixed insert/update/delete) x P in {1,2,8} x k in {1,4}
+@pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize("P", [1, 2, 8])
+@pytest.mark.parametrize("kind", list(KINDS))
+@pytest.mark.parametrize("workload", SCENARIO_WORKLOADS)
+def test_scenario_matrix_partitioned_bitwise_vs_full_recompute(
+        workload, kind, P, k, full_recompute):
+    wl, budget = scenario_workload(workload)
+    kw = KINDS[kind]
+    ref = full_recompute(workload, kind)
+    tmp_path = Path(tempfile.mkdtemp(prefix=f"part_{workload}_"))
     try:
-        wl = realize_workload(
-            generate_workload(8, seed=seed), bytes_per_root=1 << 12
+        store = DiskStore(tmp_path / f"{kind}_p{P}k{k}")
+        rep = run_partitioned_scenario(
+            wl, P, store, budget,
+            UpdateSpec(mode="incremental", **kw), CM,
+            n_compute_workers=k,
         )
-        budget = sum(n.size for n in wl.nodes) * 0.4
-        for kind, kw in KINDS.items():
-            ref = DiskStore(tmp_path / f"ref_{kind}")
-            run_scenario(wl, ref, budget, UpdateSpec(mode="full", **kw), CM)
-            for P in (1, 2, 8):
-                for k in (1, 4):
-                    store = DiskStore(tmp_path / f"{kind}_p{P}k{k}")
-                    rep = run_partitioned_scenario(
-                        wl, P, store, budget,
-                        UpdateSpec(mode="incremental", **kw), CM,
-                        n_compute_workers=k,
-                    )
-                    assert len(rep.rounds) == kw["n_rounds"] + 1
-                    if P == 1:
-                        verify_scenario_equivalence(wl, store, ref)
-                    else:
-                        verify_partitioned_equivalence(wl, store, P, ref)
-                    assert all(
-                        r.run.peak_catalog_bytes <= budget + 1e-9
-                        for r in rep.rounds
-                    ), (kind, P, k)
+        assert len(rep.rounds) == kw["n_rounds"] + 1
+        if P == 1:
+            verify_scenario_equivalence(wl, store, ref)
+        else:
+            verify_partitioned_equivalence(wl, store, P, ref)
+        assert all(
+            r.run.peak_catalog_bytes <= budget + 1e-9 for r in rep.rounds
+        ), (workload, kind, P, k)
     finally:
         shutil.rmtree(tmp_path, ignore_errors=True)
 

@@ -45,6 +45,19 @@ BASELINE = REPO / "tools" / "sc_lint_baseline.json"
 DEFAULT_REPORT = REPO / "results" / "sc_lint" / "report.json"
 
 
+def describe_dataplane() -> str:
+    """One-line report header: the data plane's configured impl and what
+    "auto" resolves to on this platform."""
+    from repro.mv import dataplane
+
+    try:
+        resolved = dataplane.resolve_impl("auto")
+    except Exception as e:  # jax missing/broken: still describable
+        resolved = f"unresolvable ({e})"
+    return (f"data plane impl: configured={dataplane.configured_impl()!r} "
+            f"resolves_to={resolved!r}")
+
+
 def _source_findings() -> list[Finding]:
     return determinism.lint_paths(REPO)
 
@@ -236,10 +249,8 @@ def main(argv=None) -> int:
     ap.add_argument("--baseline", type=Path, default=BASELINE)
     args = ap.parse_args(argv)
 
-    from repro.kernels.dispatch import describe
-
     print(f"sc-lint over {REPO}")
-    print(describe())
+    print(describe_dataplane())
     findings, counts = collect()
 
     if args.update_baseline:
@@ -266,7 +277,7 @@ def main(argv=None) -> int:
     if report_path is not None:
         report_path.parent.mkdir(parents=True, exist_ok=True)
         report_path.write_text(json.dumps({
-            "dispatch": describe(),
+            "dispatch": describe_dataplane(),
             "counts": counts,
             "baseline": sorted(baseline),
             "new_fingerprints": [f.fingerprint for f in new],
